@@ -284,8 +284,8 @@ def _mule_mesh(n_mules: int):
     print(f"distributed mesh: 1 pod x {k} mule shards "
           f"({n_dev} devices visible, n_mules={n_mules})"
           + (" — WARNING: k=1 shards nothing" if k == 1 else ""))
-    return jax.sharding.Mesh(
-        np.array(jax.devices()[:k]).reshape(1, k), ("pod", "data"))
+    from repro.launch.mesh import make_mule_mesh
+    return make_mule_mesh(1, k)
 
 
 def run_experiment(cfg: ExperimentConfig) -> Dict:

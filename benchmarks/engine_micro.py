@@ -339,12 +339,28 @@ def run_churn_bench(steps: int = 500, n_mules: int = 20, reps: int = 5,
     return rows
 
 
+def _require_host_cpu(what: str) -> None:
+    """The benches that spawn children run them on forced host CPU devices.
+
+    A child that needed an accelerator would find it held by this process
+    (one process per chip), so on anything but the CPU backend this stops
+    before any child starts and names the devices it found.
+    """
+    if jax.default_backend() != "cpu":
+        raise RuntimeError(
+            f"{what} runs child processes on forced host CPU devices, but "
+            f"this process holds {jax.devices()}; run it with "
+            "JAX_PLATFORMS=cpu")
+
+
 def _respawn_with_devices(n_devices: int, out_path: str,
                           flag: str = "--distributed",
                           out_flag: str = "--out-distributed") -> None:
     """Re-exec a device-hungry bench in a child with N forced host devices."""
+    _require_host_cpu(f"engine_micro {flag}")
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "") +
                         f" --xla_force_host_platform_device_count={n_devices}"
                         ).strip()
@@ -1082,6 +1098,7 @@ def _child_env() -> dict:
     ``-m benchmarks.engine_micro`` resolves regardless of cwd."""
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
     env["PYTHONPATH"] = (os.path.join(root, "src") + os.pathsep + root +
                          os.pathsep +
                          env.get("PYTHONPATH", "")).rstrip(os.pathsep)
@@ -1170,6 +1187,7 @@ def run_scale_bench(ms=(10_000, 32_000, 100_000), steps: int = 96,
     single-process — materializing a [T, 10^6] schedule is the thing
     this engine exists to avoid).
     """
+    _require_host_cpu("engine_micro --scale")
     out_path = os.path.abspath(out_path)
     ms = sorted(int(m) for m in ms)
     curve = []
@@ -1315,6 +1333,8 @@ if __name__ == "__main__":
     ap.add_argument("--out-roofline", default=_DEFAULT_ROOF_OUT)
     ap.add_argument("--out-scale", default=_DEFAULT_SCALE_OUT)
     args = ap.parse_args()
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
     if args.scale_child:
         _scale_child(args.scale_child)
         raise SystemExit(0)

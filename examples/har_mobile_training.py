@@ -9,6 +9,7 @@ Local over time (Fig. 8/9 analogue).
 import argparse
 
 from benchmarks.common import ExperimentConfig, run_experiment
+from repro.launch.compile_cache import use_compile_cache
 
 
 def main():
@@ -17,6 +18,7 @@ def main():
     ap.add_argument("--steps", type=int, default=200)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    use_compile_cache()
 
     print(f"HAR (LSTM-CNN over IMU windows), P_cross={args.p_cross}")
     for method in ("local", "gossip", "mlmule"):

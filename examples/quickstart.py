@@ -32,7 +32,10 @@ from repro.core import PopulationConfig, init_population
 from repro.data import dirichlet_partition, make_image_dataset
 from repro.data.partition import train_test_split
 from repro.models.cnn import accuracy, cnn_forward, init_cnn, xent_loss
+from repro.launch.compile_cache import use_compile_cache
 from repro.scenarios import get_scenario, run_population
+
+use_compile_cache()
 
 F, M, STEPS = 8, 12, 240
 

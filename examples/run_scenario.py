@@ -24,12 +24,14 @@ colocation schedule is generated chunk-by-chunk inside the compiled replay
 (``run_population_streamed`` — O(chunk*M) schedule memory instead of
 O(T*M), bitwise-identical results, composes with ``--distributed``); with
 ``--processes N`` the whole run re-execs as an N-rank local
-``jax.distributed`` cluster (gloo CPU collectives) and the mule mesh
-spans every rank's devices — same engines, same results, per-process
-state::
+``jax.distributed`` cluster on the host CPU (gloo collectives) and the
+mule mesh spans every rank's devices — same engines, same results,
+per-process state. It rehearses a multi-host run on the CPU, so it asks
+the caller to say so with ``JAX_PLATFORMS=cpu``::
 
-  PYTHONPATH=src python examples/run_scenario.py --scenario commuter \\
-      --distributed --stream --processes 2 --devices-per-process 4
+  JAX_PLATFORMS=cpu PYTHONPATH=src python examples/run_scenario.py \\
+      --scenario commuter --distributed --stream --processes 2 \\
+      --devices-per-process 4
 """
 import argparse
 import os
@@ -54,6 +56,9 @@ from repro.launch.multiprocess import (initialize_from_env,  # noqa: E402
 
 _N_PROC = int(_argv_value("--processes", "1"))
 if _N_PROC > 1 and not os.environ.get("REPRO_MP_COORDINATOR"):
+    if os.environ.get("JAX_PLATFORMS") != "cpu":
+        sys.exit("--processes runs every rank on the host CPU (it rehearses "
+                 "a multi-host run); set JAX_PLATFORMS=cpu to run it")
     n_dev = int(_argv_value("--devices-per-process", "4"))
     results = spawn_local_cluster(
         [sys.executable] + sys.argv, _N_PROC, n_dev,
@@ -80,6 +85,7 @@ initialize_from_env()
 
 from benchmarks.common import (METHODS_MOBILE, ExperimentConfig,
                                run_experiment, run_sweep_experiment)
+from repro.launch.compile_cache import use_compile_cache
 from repro.scenarios import SCENARIOS, list_scenarios
 
 
@@ -134,12 +140,13 @@ def main():
                     help="drifted-mule fraction that triggers a re-bucket "
                          "swap (see --rebucket-every)")
     ap.add_argument("--processes", type=int, default=1,
-                    help="re-exec this run as an N-process local "
-                         "jax.distributed cluster (requires --distributed; "
-                         "the mule mesh then spans every process's devices "
-                         "and n-mules must divide processes x "
-                         "devices-per-process; composes with --stream and "
-                         "--rebucket-every)")
+                    help="rehearse a multi-host run on the CPU: re-exec "
+                         "this run as an N-process local jax.distributed "
+                         "cluster of host-CPU ranks (requires --distributed "
+                         "and JAX_PLATFORMS=cpu; the mule mesh then spans "
+                         "every process's devices and n-mules must divide "
+                         "processes x devices-per-process; composes with "
+                         "--stream and --rebucket-every)")
     ap.add_argument("--devices-per-process", type=int, default=4,
                     help="forced host devices per rank for --processes")
     ap.add_argument("--coordinator", default=None, metavar="ADDR",
@@ -153,6 +160,7 @@ def main():
         for name in list_scenarios():
             print(f"{name:18s} {SCENARIOS[name].description}")
         return
+    use_compile_cache()
 
     if args.processes > 1 and not args.distributed:
         ap.error("--processes shards the population across a cluster; "

@@ -12,6 +12,7 @@ baselines drive the same precomputed co-location tensors step by step.
 import argparse
 
 from benchmarks.common import ExperimentConfig, run_experiment
+from repro.launch.compile_cache import use_compile_cache
 
 
 def main():
@@ -23,6 +24,7 @@ def main():
     ap.add_argument("--scenario", default="",
                     help="registry scenario name (overrides dist/pattern)")
     args = ap.parse_args()
+    use_compile_cache()
 
     print(f"distribution={args.dist} mobility P_cross={args.pattern}"
           + (f" scenario={args.scenario}" if args.scenario else ""))

@@ -23,6 +23,7 @@ from repro.configs import get_smoke_config
 from repro.core import PopulationConfig, init_population, population_step
 from repro.core.freshness import FreshnessConfig
 from repro.data import make_lm_dataset
+from repro.launch.compile_cache import use_compile_cache
 from repro.mobility import MobilityConfig, init_mobility, mobility_step
 from repro.models import build_model
 
@@ -36,6 +37,7 @@ def main():
     ap.add_argument("--n-fixed", type=int, default=4)
     ap.add_argument("--n-mules", type=int, default=6)
     args = ap.parse_args()
+    use_compile_cache()
 
     cfg = get_smoke_config(args.arch)
     model = build_model(cfg)

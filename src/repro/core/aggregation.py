@@ -75,7 +75,7 @@ def masked_group_mean(models: Any, assign: jnp.ndarray, *,
         flat = jnp.concatenate(
             [l.reshape(l.shape[0], -1).astype(jnp.float32) for l in leaves], axis=1)
         out = mule_agg(norm.astype(jnp.float32), flat,
-                       interpret=(backend == "interpret"))
+                       interpret=True if backend == "interpret" else None)
         outs, off = [], 0
         for s, n, l in zip(shapes, sizes, leaves):
             outs.append(out[:, off:off + n].reshape((out.shape[0],) + s).astype(l.dtype))

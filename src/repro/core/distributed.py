@@ -61,7 +61,6 @@ from typing import Any, Callable, Dict
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core.freshness import (FreshnessConfig, age_histogram,
@@ -143,9 +142,9 @@ def _bucket_order_program(mesh: Mesh, data_axis: str, n_shards: int,
         order = jnp.argsort(full, stable=True).astype(jnp.int32)
         return order, full
 
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         order_fn, mesh=mesh, in_specs=(P(data_axis),),
-        out_specs=(P(), P()), check_rep=False))
+        out_specs=(P(), P()), check_vma=False))
 
 
 def global_bucket_order(area_last, mesh, data_axis: str = "data"):
@@ -266,11 +265,11 @@ def migrate_mules(mule_models: Any, move_mask: jnp.ndarray, mesh: Mesh,
             return jnp.where(m, recv, leaf)
         return jax.tree.map(one, models)
 
-    sharded = shard_map(
+    sharded = jax.shard_map(
         swap, mesh=mesh,
         in_specs=(P(data_axis), P(data_axis)),
         out_specs=P(data_axis),
-        check_rep=False)
+        check_vma=False)
     return sharded(mule_models, move_mask)
 
 

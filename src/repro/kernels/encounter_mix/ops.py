@@ -2,20 +2,18 @@
 
 ``backend``:
 - ``"ref"``       — the jnp oracle (engine default; exact, CPU-friendly).
-- ``"pallas"``    — the tiled kernel, compiled (TPU) or interpreted per the
-                    same autodetect/env override as ``mule_agg``.
+- ``"pallas"``    — the tiled kernel, compiled on a TPU and interpreted
+                    elsewhere, unless the caller passes ``interpret``.
 - ``"interpret"`` — the tiled kernel, interpreter forced.
 - ``"auto"``      — pallas on TPU, ref elsewhere.
 
-``REPRO_PALLAS_INTERPRET`` overrides the interpret autodetect exactly like
-``repro.kernels.mule_agg.ops``.
-
 Tile sizes: ``block_m``/``block_d`` left as ``None`` consult the autotune
-cache (``repro.launch.autotune.tuned_encounter_blocks`` — the measured
-selection committed in ``benchmarks/BENCH_roofline.json``, nearest tuned
-[M, D] shape) and fall back to the pre-tuning hand defaults (256, 2048)
-without one. Explicit values always win; ``REPRO_TUNE_CACHE`` repoints
-(or, empty, disables) the cache.
+cache (``repro.launch.autotune.tuned_encounter_blocks`` — the selection
+committed in ``benchmarks/BENCH_roofline.json``, nearest tuned [M, D]
+shape) and fall back to the pre-tuning hand defaults (256, 2048) without
+one. Explicit values win; either way the kernel legalizes the tiles and
+fits them to VMEM. ``REPRO_TUNE_CACHE`` repoints (or, empty, disables)
+the cache.
 """
 from __future__ import annotations
 
@@ -28,7 +26,6 @@ from repro.kernels.encounter_mix.kernel import (encounter_hop_pallas,
                                                 encounter_mix_pallas)
 from repro.kernels.encounter_mix.ref import (  # noqa: F401
     encounter_block, encounter_gate, encounter_mix_reference, normalize_mix)
-from repro.kernels.mule_agg.ops import _env_interpret
 
 
 def _resolve(backend: str, interpret: Optional[bool]):
@@ -38,8 +35,6 @@ def _resolve(backend: str, interpret: Optional[bool]):
     if backend not in ("ref", "pallas", "interpret"):
         raise ValueError(f"unknown encounter_mix backend {backend!r}; "
                          "expected ref | pallas | interpret | auto")
-    if interpret is None:
-        interpret = _env_interpret()
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     if backend == "interpret":

@@ -90,7 +90,7 @@ def flash_attention_pallas(q, k, v, *, causal: bool = True,
                            window: Optional[int] = None,
                            block_q: int = 128, block_k: int = 128,
                            scale: Optional[float] = None,
-                           interpret: bool = True):
+                           interpret: bool):
     """q: [B,S,H,D]; k,v: [B,Sk,KV,D] -> [B,S,H,D]."""
     b, s, h, d = q.shape
     _, sk, n_kv, _ = k.shape

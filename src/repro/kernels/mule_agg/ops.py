@@ -1,24 +1,21 @@
 """Dispatching wrapper for the fused population aggregation.
 
-``block_d`` tuning: the kernel streams [M, block_d] tiles; too small pays
-grid overhead, too large overflows VMEM residency. ``block_d=None`` uses
-``pick_block_d``, which consults the autotune cache — the measured
-selection committed in ``benchmarks/BENCH_roofline.json`` by
+``block_d`` tuning: the kernel streams [block_k, block_d] tiles; too small
+pays grid overhead, too large overflows VMEM. ``block_d=None`` uses
+``pick_block_d``, which consults the autotune cache — the selection
+committed in ``benchmarks/BENCH_roofline.json`` by
 ``repro.launch.autotune`` (re-measure with
 ``python -m benchmarks.engine_micro --roofline``; the selection is the
-argmin of a per-shape candidate sweep on this container's interpret path,
-which tracks relative block behaviour, not TPU latency) — and falls back
-to the hand-measured constant below when no cache is available.
+argmin of a per-shape candidate sweep on the CPU interpret path, which
+tracks relative block behaviour, not TPU latency) — and falls back to the
+hand-measured constant below when no cache is available. Whatever the
+request, the kernel itself legalizes the tile and fits it to VMEM.
 ``REPRO_TUNE_CACHE`` repoints (or, empty, disables) the cache.
 
-``REPRO_PALLAS_INTERPRET`` overrides the interpret-mode autodetect for
-every call that doesn't pass ``interpret`` explicitly: set to ``1``/``0``
-to force the Pallas interpreter on/off (e.g. exercising the kernel path in
-CI on CPU, or dry-running TPU lowering).
+``interpret`` follows the platform (compiled on a TPU, the Pallas
+interpreter elsewhere) unless the caller passes it.
 """
 from __future__ import annotations
-
-import os
 
 import jax
 
@@ -26,10 +23,8 @@ from repro.kernels.mule_agg.kernel import mule_agg_pallas
 from repro.kernels.mule_agg.ref import mule_agg_reference  # noqa: F401
 
 # Pre-cache fallback, measured by the retired kernels_micro block_d sweep on
-# this container: the sweep came out monotone at every D (2^12..2^18) —
-# per-tile dispatch overhead dominates, so the largest tile always won
-# (4096 beat 2048 by ~1.9x at D=2^18). Capped at 4096 to keep the
-# [M, block_d] tile + [F, block_d] output VMEM-resident at realistic M.
+# the CPU interpret path: the sweep came out monotone at every D (2^12..2^18)
+# — per-tile dispatch overhead dominates, so the largest tile always won.
 _BLOCK_D_MEASURED = 4096
 
 
@@ -40,22 +35,13 @@ def pick_block_d(d: int) -> int:
     return tuned_block_d(d, default=_BLOCK_D_MEASURED)
 
 
-def _env_interpret() -> bool | None:
-    val = os.environ.get("REPRO_PALLAS_INTERPRET")
-    if not val:                    # unset or empty -> keep the autodetect
-        return None
-    return val.lower() not in ("0", "false")
-
-
 def mule_agg(assign, weights, *, block_d: int | None = None,
              backend: str = "auto", interpret: bool | None = None):
     """assign [F, M] x weights [M, D] -> [F, D]."""
-    if interpret is None:
-        interpret = _env_interpret()
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     if backend == "ref":
         return mule_agg_reference(assign, weights)
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
     if block_d is None:
         block_d = pick_block_d(weights.shape[1])
     return mule_agg_pallas(assign, weights, block_d=block_d, interpret=interpret)

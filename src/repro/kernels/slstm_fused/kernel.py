@@ -67,7 +67,7 @@ def _slstm_kernel(pre_ref, r_ref, h_out_ref, h_scr, c_scr, n_scr, m_scr):
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def slstm_scan_pallas(pre, r, *, interpret: bool = True):
+def slstm_scan_pallas(pre, r, *, interpret: bool):
     """pre: [B, S, 4, H, P]; r: [4, H, P, P] -> h [B, S, H, P]."""
     b, s, four, h, p = pre.shape
     assert four == 4

@@ -69,7 +69,7 @@ def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, state_scr, *,
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
 def ssd_scan_pallas(x, dt, A, Bmat, Cmat, *, chunk: int = 64,
-                    init_state=None, interpret: bool = True):
+                    init_state=None, interpret: bool):
     """x [B,S,H,P]; dt [B,S,H]; A [H]; Bmat/Cmat [B,S,N] -> (y, final_state).
 
     final_state is not returned by the kernel (scratch); callers needing the

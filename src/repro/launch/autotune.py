@@ -14,19 +14,19 @@ records.
 
 **Kernel tuning.** ``tune_mule_agg`` / ``tune_encounter_mix`` generalize the
 old hand table in ``repro.kernels.mule_agg.ops`` (one measured constant) and
-the hand defaults in ``encounter_mix``: every candidate block size that fits
-the VMEM residency model (tile footprints priced via the shared dtype table)
-is timed on this container's interpret path — which tracks *relative* block
-behaviour, not TPU latency, exactly like the retired
-``kernels_micro.run_block_d_sweep`` — and the argmin wins. Selections land
-in the tuning cache section of ``benchmarks/BENCH_roofline.json``; the
-kernel wrappers look their block sizes up there at call time
-(``tuned_block_d`` / ``tuned_encounter_blocks``) and fall back to the old
-hand defaults when the cache is absent.
+the hand defaults in ``encounter_mix``: every candidate block size, as the
+kernel would legalize and fit it to VMEM (``repro.kernels.tiling``), is
+timed on the CPU interpret path — which tracks *relative* block behaviour,
+not TPU latency, exactly like the retired ``kernels_micro.run_block_d_sweep``
+— and the argmin wins. Selections land in the tuning cache section of
+``benchmarks/BENCH_roofline.json``; the kernel wrappers look their block
+sizes up there at call time (``tuned_block_d`` / ``tuned_encounter_blocks``)
+and fall back to the old hand defaults when the cache is absent. The
+kernels make whatever tile they are given legal for the TPU lowering and
+fit it to VMEM (``fit_blocks``), so a lookup never needs to.
 
 ``REPRO_TUNE_CACHE`` points the lookup at a different cache file (tests use
-it; an empty value disables the cache entirely). ``REPRO_PALLAS_INTERPRET``
-keeps its meaning in the kernel wrappers — tuning never touches it.
+it; an empty value disables the cache entirely).
 
 The committed artifact is a *ratchet*: ``benchmarks/bench_gate.py`` validates
 its schema on every tier-1 push and fails the CI slow lane if a freshly
@@ -42,6 +42,8 @@ import os
 import time
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
+from repro.kernels.tiling import VMEM_BUDGET_BYTES
+
 # ---------------------------------------------------------------------------
 # tuning cache: the runtime half (no jax import needed to look up a block)
 # ---------------------------------------------------------------------------
@@ -54,10 +56,6 @@ DEFAULT_CACHE_PATH = os.path.join(_REPO_ROOT, "benchmarks",
 
 _UNSET = object()
 _cache_memo: Any = _UNSET
-
-# VMEM residency budget for candidate feasibility (one v5e core; the model
-# prices the per-grid-step tile working set, not whole-array HBM footprints)
-VMEM_BUDGET_BYTES = 16 * 2 ** 20
 
 MULE_AGG_BLOCK_D_CANDIDATES = (256, 512, 1024, 2048, 4096)
 ENCOUNTER_BLOCK_M_CANDIDATES = (128, 256, 512)
@@ -190,23 +188,6 @@ def suggest_mesh_shape(method: str, n_mules: int,
 
 
 # ---------------------------------------------------------------------------
-# VMEM feasibility model (per-grid-step tile working set, f32 accumulators)
-# ---------------------------------------------------------------------------
-
-
-def mule_agg_tile_bytes(f: int, m: int, block_d: int) -> int:
-    """Resident A [F, M] + streamed W [M, block_d] + out [F, block_d]."""
-    return 4 * (f * m + m * block_d + f * block_d)
-
-
-def encounter_tile_bytes(m: int, block_m: int, block_d: int) -> int:
-    """Resident geometry [4, M] strip + row block [4, block_m] + streamed
-    W [M, block_d] + out [block_m, block_d] + the [block_m, M] mask strip."""
-    return 4 * (4 * m + 4 * block_m + m * block_d + block_m * block_d
-                + block_m * m)
-
-
-# ---------------------------------------------------------------------------
 # measured kernel tuning (interpret path: relative block behaviour)
 # ---------------------------------------------------------------------------
 
@@ -228,22 +209,20 @@ def tune_mule_agg(f: int = 8, m: int = 64, d: int = 65536, *,
                   ) -> Dict:
     """Measure every feasible ``block_d`` candidate; argmin wins."""
     import jax
-    from repro.kernels.mule_agg.kernel import mule_agg_pallas
+    from repro.kernels.mule_agg.kernel import fit_blocks, mule_agg_pallas
 
     k1, k2 = jax.random.split(jax.random.PRNGKey(0))
     assign = jax.random.uniform(k1, (f, m))
     w = jax.random.normal(k2, (m, d))
     times: Dict[str, float] = {}
-    # the kernel clamps block_d to max(128, d); dedupe on the clamped value
+    # dedupe on the tile the kernel actually uses (legal and VMEM-fitted),
     # so tiny shapes still have at least one candidate
-    for block_d in sorted({min(b, max(128, d)) for b in candidates}):
-        if mule_agg_tile_bytes(f, m, block_d) > VMEM_BUDGET_BYTES:
-            continue
+    for block_d in sorted({fit_blocks(f, m, d, b)[1] for b in candidates}):
         times[str(block_d)] = _median_us(
             lambda b=block_d: mule_agg_pallas(assign, w, block_d=b,
                                               interpret=True), reps)
     best = min(times, key=times.get)
-    default = str(min(MULE_AGG_DEFAULT_BLOCK_D, max(128, d)))
+    default = str(fit_blocks(f, m, d, MULE_AGG_DEFAULT_BLOCK_D)[1])
     return {"f": f, "m": m, "d": d, "block_d": int(best),
             "candidates_us": {k: round(v, 1) for k, v in times.items()},
             "speedup_vs_default": round(times[default] / times[best], 3)
@@ -258,7 +237,8 @@ def tune_encounter_mix(m: int = 1024, d: int = 480, *, reps: int = 3,
                        = ENCOUNTER_BLOCK_D_CANDIDATES) -> Dict:
     """Measure every feasible (block_m, block_d) candidate; argmin wins."""
     import jax
-    from repro.kernels.encounter_mix.kernel import encounter_mix_pallas
+    from repro.kernels.encounter_mix.kernel import (encounter_mix_pallas,
+                                                    fit_blocks)
 
     ks = jax.random.split(jax.random.PRNGKey(1), 4)
     pos = jax.random.uniform(ks[0], (m, 2))
@@ -266,22 +246,19 @@ def tune_encounter_mix(m: int = 1024, d: int = 480, *, reps: int = 3,
     active = jax.random.uniform(ks[2], (m,)) < 0.9
     w = jax.random.normal(ks[3], (m, d))
     times: Dict[str, float] = {}
-    # candidates clamp exactly like the kernel does; dedupe on the clamped
-    # pair so tiny shapes still have at least one candidate
-    pairs = sorted({(min(bm, max(8, m)), min(bd, max(128, d)))
-                    for bm in block_m_candidates
+    # dedupe on the tiles the kernel actually uses (legal and VMEM-fitted),
+    # so tiny shapes still have at least one candidate
+    fitted = lambda bm, bd: fit_blocks(m, m, d, bm, bd)[::2]
+    pairs = sorted({fitted(bm, bd) for bm in block_m_candidates
                     for bd in block_d_candidates})
     for bm, bd in pairs:
-        if encounter_tile_bytes(m, bm, bd) > VMEM_BUDGET_BYTES:
-            continue
         times[f"{bm}x{bd}"] = _median_us(
             lambda bm=bm, bd=bd: encounter_mix_pallas(
                 pos, area, active, w, radius=radius, block_m=bm,
                 block_d=bd, interpret=True)[0], reps)
     best = min(times, key=times.get)
     bm, bd = (int(x) for x in best.split("x"))
-    dm, dd = ENCOUNTER_DEFAULT_BLOCKS
-    default = f"{min(dm, max(8, m))}x{min(dd, max(128, d))}"
+    default = "{}x{}".format(*fitted(*ENCOUNTER_DEFAULT_BLOCKS))
     return {"m": m, "d": d, "block_m": bm, "block_d": bd,
             "candidates_us": {k: round(v, 1) for k, v in times.items()},
             "speedup_vs_default": round(times[default] / times[best], 3)

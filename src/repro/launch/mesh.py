@@ -66,9 +66,8 @@ def make_mule_mesh(pod: int, data: int, *, pod_axis: str = "pod",
     The shape the roofline-driven ``suggest_mesh_shape`` emits and
     ``run_population_distributed(mesh=None)`` consumes; ``pod_axis=""``
     builds the single-axis data-only mesh a podless ``DistributedConfig``
-    expects. Plain ``jax.make_mesh`` (no axis-type annotations) so it works
-    on every jax the repo supports. Under multi-process bring-up the mesh
-    spans every process's devices — pass the *global* shard counts.
+    expects. Under multi-process bring-up the mesh spans every process's
+    devices — pass the *global* shard counts.
     """
     if not pod_axis:
         if pod != 1:

@@ -152,12 +152,14 @@ def put_global(x, mesh, spec):
     """Commit ``x`` to ``NamedSharding(mesh, spec)``, multi-host safe.
 
     Arrays that are already global (not fully addressable — i.e. already
-    placed on a multi-host mesh) pass through untouched.  Leaves whose
-    leading axis is sharded hand jax only this process's contiguous row
-    block via ``jax.make_array_from_process_local_data``; everything
-    else (replicated leaves, scalars, keys) goes through
-    ``jax.make_array_from_callback``, which only materializes
-    addressable shards.
+    placed on a multi-host mesh) pass through untouched.  In a single
+    process this is ``jax.device_put`` (device to device, a no-op for an
+    array already placed so).  Across processes, leaves whose leading
+    axis is sharded hand jax only this process's contiguous row block via
+    ``jax.make_array_from_process_local_data``; everything else
+    (replicated leaves, scalars, keys) goes through
+    ``jax.make_array_from_callback``, which only materializes addressable
+    shards.
     """
     import jax
     from jax.sharding import NamedSharding
@@ -165,6 +167,8 @@ def put_global(x, mesh, spec):
     if isinstance(x, jax.Array) and not x.is_fully_addressable:
         return x
     sharding = NamedSharding(mesh, spec)
+    if jax.process_count() == 1:
+        return jax.device_put(x, sharding)
     arr = np.asarray(x)
     row_sharded = (arr.ndim > 0 and len(spec) > 0 and spec[0] is not None)
     if row_sharded:

@@ -104,7 +104,6 @@ def apply_moe(params, x, cfg: ModelConfig, *, mesh: Any = None,
 
     if mesh is not None and ep_axis is None:
         from jax.sharding import PartitionSpec as P
-        from jax.experimental.shard_map import shard_map
 
         dp_size = 1
         for a in dp_axes:
@@ -125,9 +124,9 @@ def apply_moe(params, x, cfg: ModelConfig, *, mesh: Any = None,
             return (out.reshape(bl, s, d).astype(xs.dtype),
                     jax.lax.pmean(aux, dp_axes))
 
-        fn = shard_map(dp_moe, mesh=mesh,
-                       in_specs=(P(), P(), P(), P(), P(b_shard)),
-                       out_specs=(P(b_shard), P()), check_rep=False)
+        fn = jax.shard_map(dp_moe, mesh=mesh,
+                           in_specs=(P(), P(), P(), P(), P(b_shard)),
+                           out_specs=(P(b_shard), P()), check_vma=False)
         return fn(params["router"], params["wi_gate"], params["wi_up"],
                   params["wo"], x)
 
@@ -145,7 +144,6 @@ def apply_moe(params, x, cfg: ModelConfig, *, mesh: Any = None,
 
     # ---- expert-parallel shard_map path -----------------------------------
     from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
 
     ep = mesh.shape[ep_axis]
     dp_size = 1
@@ -190,7 +188,7 @@ def apply_moe(params, x, cfg: ModelConfig, *, mesh: Any = None,
 
     in_specs = (P(), P(ep_axis), P(ep_axis), P(ep_axis), P(b_shard))
     out_specs = (P(b_shard), P())
-    fn = shard_map(local_moe, mesh=mesh, in_specs=in_specs,
-                   out_specs=out_specs, check_rep=False)
+    fn = jax.shard_map(local_moe, mesh=mesh, in_specs=in_specs,
+                       out_specs=out_specs, check_vma=False)
     return fn(params["router"], params["wi_gate"], params["wi_up"],
               params["wo"], x)

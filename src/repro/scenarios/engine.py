@@ -463,12 +463,11 @@ def get_compiled_replay(state, fid, exch, pos, area, act, batches, context,
     if vmapped:
         core = jax.vmap(core)
     if mesh is not None:
-        from jax.experimental.shard_map import shard_map
         in_specs, out_specs = _distributed_specs(
             state, batches, dcfg, vmapped=vmapped,
             area_dyn=np.ndim(area) == np.ndim(fid))
-        core = shard_map(core, mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs, check_rep=False)
+        core = jax.shard_map(core, mesh=mesh, in_specs=in_specs,
+                             out_specs=out_specs, check_vma=False)
     fn = jax.jit(core, donate_argnums=(0,) if donate else ())
     _JIT_CACHE[cache_key] = fn
     while len(_JIT_CACHE) > _JIT_CACHE_MAX:
@@ -566,11 +565,10 @@ def get_compiled_chunk_replay(state, generator, gen_arrays, batches, context,
                                pmean_axis=(dcfg.data_axis
                                            if mesh is not None else None))
     if mesh is not None:
-        from jax.experimental.shard_map import shard_map
         in_specs, out_specs = _streamed_specs(state, generator, batches,
                                               dcfg, rebucket=rebucket)
-        core = shard_map(core, mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs, check_rep=False)
+        core = jax.shard_map(core, mesh=mesh, in_specs=in_specs,
+                             out_specs=out_specs, check_vma=False)
     fn = jax.jit(core, donate_argnums=(0, 1) if donate else ())
     _JIT_CACHE[cache_key] = fn
     while len(_JIT_CACHE) > _JIT_CACHE_MAX:
@@ -1066,23 +1064,23 @@ def run_population_distributed(state: Dict[str, Any],
         mesh = _auto_mesh(method, fid.shape[1], dcfg)
     _check_mule_sharding(fid.shape[1], mesh, dcfg)
     stacked = None if callable(batches) else batches
-    if jax.process_count() > 1:
-        # multi-process mesh: commit every input explicitly so each
-        # process materializes only its shard of the mule columns
-        from jax.sharding import PartitionSpec as P
-        from repro.launch.multiprocess import put_global, put_global_tree
-        in_specs, _ = _distributed_specs(state, batches, dcfg, vmapped=False,
-                                         area_dyn=area.ndim == 2)
-        state = put_global_tree(state, mesh, in_specs[0])
-        fid, exch, pos, area, act = (
-            put_global(x, mesh, s) for x, s in
-            zip((fid, exch, pos, area, act), in_specs[1:6]))
-        if stacked is not None:
-            stacked = put_global_tree(stacked, mesh, in_specs[6])
-        if context is not None:
-            context = put_global_tree(
-                context, mesh, jax.tree.map(lambda _: P(), context))
-        key = put_global(key, mesh, P())
+    # commit every input to the mesh explicitly: each device holds its
+    # shard of the mule columns (and, across processes, each process
+    # materializes only its own shards)
+    from jax.sharding import PartitionSpec as P
+    from repro.launch.multiprocess import put_global, put_global_tree
+    in_specs, _ = _distributed_specs(state, batches, dcfg, vmapped=False,
+                                     area_dyn=area.ndim == 2)
+    state = put_global_tree(state, mesh, in_specs[0])
+    fid, exch, pos, area, act = (
+        put_global(x, mesh, s) for x, s in
+        zip((fid, exch, pos, area, act), in_specs[1:6]))
+    if stacked is not None:
+        stacked = put_global_tree(stacked, mesh, in_specs[6])
+    if context is not None:
+        context = put_global_tree(
+            context, mesh, jax.tree.map(lambda _: P(), context))
+    key = put_global(key, mesh, P())
     fn = get_compiled_replay(state, fid, exch, pos, area, act, batches,
                              context, key, train_fn, dcfg.pop, method=method,
                              eval_every=eval_every, eval_fn=eval_fn,
@@ -1116,7 +1114,6 @@ def run_population_distributed_loop(state: Dict[str, Any],
     Returns ``(final_state, last_fid)`` (``last_fid`` sharded like the
     mule axis).
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
     from repro.core.distributed import make_distributed_method_step
 
@@ -1144,10 +1141,10 @@ def run_population_distributed_loop(state: Dict[str, Any],
             _STATS["traces"] += 1      # python side effect: fires per trace
             return step_core(st, info, bt, k)
 
-        step = jax.jit(shard_map(
+        step = jax.jit(jax.shard_map(
             counted, mesh=mesh,
             in_specs=(state_specs, info_specs, P(), P()),
-            out_specs=state_specs, check_rep=False))
+            out_specs=state_specs, check_vma=False))
         _JIT_CACHE[cache_key] = step
         while len(_JIT_CACHE) > _JIT_CACHE_MAX:
             _JIT_CACHE.popitem(last=False)

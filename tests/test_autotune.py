@@ -1,20 +1,22 @@
-"""Autotuner tests: cache lookups (env repoint / disable / fallback),
-engine roofline rows, artifact production, and the differential-numerics
-invariant — every block size the tuner may select yields BITWISE-identical
-kernel output, so autotuning can change performance but never results."""
+"""Autotuner tests: cache lookups (env repoint / disable / fallback; the
+kernels make any tile TPU-legal), engine roofline rows, artifact production, and the
+differential-numerics invariant — every block size the tuner may select
+yields the same kernel output (bitwise where the padded shape is the same,
+to a stated tolerance elsewhere), so autotuning can change performance but
+not results."""
 import json
 
 import jax
 import numpy as np
 import pytest
 
+from repro.kernels.tiling import VMEM_BUDGET_BYTES
 from repro.launch import autotune
 from repro.launch.autotune import (ENCOUNTER_BLOCK_D_CANDIDATES,
                                    ENCOUNTER_BLOCK_M_CANDIDATES,
                                    MULE_AGG_BLOCK_D_CANDIDATES,
-                                   VMEM_BUDGET_BYTES, analyze_engine_step,
-                                   encounter_tile_bytes, mule_agg_tile_bytes,
-                                   tuned_block_d, tuned_encounter_blocks,
+                                   analyze_engine_step, tuned_block_d,
+                                   tuned_encounter_blocks,
                                    tuning_cache_clear)
 
 
@@ -54,11 +56,41 @@ def test_cache_lookup_nearest_shape(tmp_path, monkeypatch):
     assert tuned_encounter_blocks(3000, 480) == (512, 1024)
 
 
+def test_kernels_legalize_whatever_the_cache_returns(tmp_path, monkeypatch):
+    """The lookups hand back what the cache stores (here tiles clamped to a
+    small tuning shape); the kernels turn it into tiles the TPU lowering
+    accepts at the queried shape: block_d a multiple of 128 or the whole D,
+    block_m and the mule tile a multiple of 8 or the whole M."""
+    from repro.kernels.encounter_mix import kernel as enc
+    from repro.kernels.mule_agg import kernel as agg
+    cache = tmp_path / "cache.json"
+    _write_cache(cache, {
+        "mule_agg": [{"f": 8, "m": 64, "d": 500, "block_d": 500}],
+        "encounter_mix": [{"m": 300, "d": 480, "block_m": 300,
+                           "block_d": 480}]})
+    monkeypatch.setenv("REPRO_TUNE_CACHE", str(cache))
+    tuning_cache_clear()
+    assert tuned_block_d(546_484) == 500
+    assert tuned_encounter_blocks(512, 546_484) == (300, 480)
+    for m, d in ((64, 500), (64, 520), (1100, 546_484)):
+        bk, bd = agg.fit_blocks(8, m, d, tuned_block_d(d))
+        assert bd == d or bd % 128 == 0, (d, bd)
+        assert bk == m or bk % 8 == 0, (m, bk)
+    for m, d in ((300, 480), (512, 546_484), (1024, 520), (20, 130)):
+        bm, bk, bd = enc.fit_blocks(m, m, d, *tuned_encounter_blocks(m, d))
+        assert bm == m or bm % 8 == 0, (m, bm)
+        assert bk == m or bk % 8 == 0, (m, bk)
+        assert bd == d or bd % 128 == 0, (d, bd)
+    assert enc.fit_blocks(512, 512, 546_484,
+                          *tuned_encounter_blocks(512, 546_484)) \
+        == (296, 512, 384)
+
+
 def test_cache_env_empty_disables(monkeypatch):
     monkeypatch.setenv("REPRO_TUNE_CACHE", "")
     tuning_cache_clear()
     assert tuned_block_d(1 << 18) == autotune.MULE_AGG_DEFAULT_BLOCK_D
-    assert tuned_encounter_blocks(1024, 480) == \
+    assert tuned_encounter_blocks(1024, 4800) == \
         autotune.ENCOUNTER_DEFAULT_BLOCKS
 
 
@@ -70,7 +102,7 @@ def test_cache_missing_or_malformed_falls_back(tmp_path, monkeypatch):
     bad.write_text("{not json")
     monkeypatch.setenv("REPRO_TUNE_CACHE", str(bad))
     tuning_cache_clear()
-    assert tuned_encounter_blocks(64, 64) == \
+    assert tuned_encounter_blocks(640, 4096) == \
         autotune.ENCOUNTER_DEFAULT_BLOCKS
     # schema-valid JSON without a tuned section reads as "no cache" too
     bad.write_text(json.dumps({"tuned": "oops"}))
@@ -118,12 +150,14 @@ def test_mule_agg_bitwise_identical_across_candidates():
     k1, k2 = jax.random.split(jax.random.PRNGKey(3))
     assign = jax.random.uniform(k1, (f, m))
     w = jax.random.normal(k2, (m, d))
-    from repro.kernels.mule_agg.kernel import mule_agg_pallas
+    from repro.kernels.mule_agg.kernel import fit_blocks, mule_agg_pallas
     from repro.kernels.mule_agg.ref import mule_agg_reference
-    blocks = sorted({min(b, max(128, d)) for b in MULE_AGG_BLOCK_D_CANDIDATES
-                     if mule_agg_tile_bytes(f, m, min(b, max(128, d)))
-                     <= VMEM_BUDGET_BYTES})
+    # D tiles never change the contraction (it runs over mules), so every
+    # candidate is pinned bitwise
+    blocks = sorted({fit_blocks(f, m, d, b)[1]
+                     for b in MULE_AGG_BLOCK_D_CANDIDATES})
     assert len(blocks) >= 3                    # a real sweep, not one cell
+    assert all(b == d or b % 128 == 0 for b in blocks)
     outs = [np.asarray(mule_agg_pallas(assign, w, block_d=b, interpret=True))
             for b in blocks]
     for b, o in zip(blocks[1:], outs[1:]):
@@ -134,36 +168,47 @@ def test_mule_agg_bitwise_identical_across_candidates():
 
 
 def test_encounter_mix_bitwise_identical_across_candidates():
-    # M divides every block_m candidate so the padded contraction length is
-    # the same for all tiles (block_m changes it otherwise, and a different
-    # reduction length is not bitwise-stable on CPU — see the padded test
-    # below); D stays indivisible to exercise column padding
+    # M fits one contraction tile, so every candidate reduces over the same
+    # peers in the same order; D stays indivisible to exercise ragged
+    # column tiles. Candidates whose padded D is the same run the same
+    # matmul shapes and are pinned bitwise; across padded widths the CPU
+    # interpreter's matmul may round differently (its blocking follows the
+    # operand width), so those agree to 1e-6 — a few f32 ulps of values of
+    # order 1, far below the 2e-5 oracle tolerance below.
     m, d = 512, 520
     ks = jax.random.split(jax.random.PRNGKey(4), 4)
     pos = jax.random.uniform(ks[0], (m, 2))
     area = jax.random.randint(ks[1], (m,), 0, 2)
     active = jax.random.uniform(ks[2], (m,)) < 0.9
     w = jax.random.normal(ks[3], (m, d))
-    from repro.kernels.encounter_mix.kernel import encounter_mix_pallas
+    from repro.kernels.encounter_mix.kernel import (encounter_mix_pallas,
+                                                    fit_blocks)
     from repro.kernels.encounter_mix.ref import encounter_mix_reference
-    pairs = sorted({(min(bm, max(8, m)), min(bd, max(128, d)))
+    pairs = sorted({fit_blocks(m, m, d, bm, bd)[::2]
                     for bm in ENCOUNTER_BLOCK_M_CANDIDATES
-                    for bd in ENCOUNTER_BLOCK_D_CANDIDATES
-                    if encounter_tile_bytes(m, min(bm, max(8, m)),
-                                            min(bd, max(128, d)))
-                    <= VMEM_BUDGET_BYTES})
+                    for bd in ENCOUNTER_BLOCK_D_CANDIDATES})
     assert len(pairs) >= 4
-    outs = []
+    assert all(bm % 8 == 0 and (bd == d or bd % 128 == 0)
+               for bm, bd in pairs)
+    outs = {}
     for bm, bd in pairs:
         mix, mass = encounter_mix_pallas(pos, area, active, w, radius=0.12,
                                          block_m=bm, block_d=bd,
                                          interpret=True)
-        outs.append((np.asarray(mix), np.asarray(mass)))
-    for (bm, bd), (mix, mass) in zip(pairs[1:], outs[1:]):
-        assert np.array_equal(outs[0][0], mix), \
-            f"blocks ({bm},{bd}) changed the mix"
-        assert np.array_equal(outs[0][1], mass), \
+        outs[bm, bd] = (np.asarray(mix), np.asarray(mass))
+    first = {}
+    for (bm, bd), (mix, mass) in outs.items():
+        d_pad = -(-d // bd) * bd
+        if d_pad in first:
+            assert np.array_equal(first[d_pad][0], mix), \
+                f"blocks ({bm},{bd}) changed the mix"
+        else:
+            first[d_pad] = (mix, mass)
+        base_mix, base_mass = outs[pairs[0]]
+        assert np.array_equal(base_mass, mass), \
             f"blocks ({bm},{bd}) changed the mass"
+        np.testing.assert_allclose(mix, base_mix, rtol=1e-6, atol=1e-6)
+    outs = [outs[p] for p in pairs]
     ref_mix, ref_mass = encounter_mix_reference(pos, area, active, w,
                                                 radius=0.12)
     np.testing.assert_allclose(outs[0][1], np.asarray(ref_mass),
@@ -197,14 +242,26 @@ def test_encounter_mix_padded_rows_still_exact_vs_reference():
 
 
 def test_tile_models_fit_the_budget():
-    # every default-shape candidate the tuner sweeps must be VMEM-feasible
-    for b in MULE_AGG_BLOCK_D_CANDIDATES:
-        assert mule_agg_tile_bytes(8, 64, b) <= VMEM_BUDGET_BYTES
-    assert encounter_tile_bytes(2048, 256, 1024) <= VMEM_BUDGET_BYTES
-    # and the model is monotone in each tile dim
-    assert mule_agg_tile_bytes(8, 64, 512) < mule_agg_tile_bytes(8, 64, 1024)
-    assert encounter_tile_bytes(512, 128, 256) < \
-        encounter_tile_bytes(512, 256, 256)
+    from repro.kernels.encounter_mix import kernel as enc
+    from repro.kernels.mule_agg import kernel as agg
+    # every candidate the tuner sweeps is fitted into the VMEM budget, at
+    # the tuning shapes and at the published mule-CNN width alike
+    for m, d in ((64, 65536), (512, 546_484), (4096, 546_484)):
+        for b in MULE_AGG_BLOCK_D_CANDIDATES:
+            bk, bd = agg.fit_blocks(8, m, d, b)
+            assert agg.tile_bytes(8, bk, bd) <= VMEM_BUDGET_BYTES
+        for bm in ENCOUNTER_BLOCK_M_CANDIDATES:
+            for b in ENCOUNTER_BLOCK_D_CANDIDATES:
+                tm, tk, td = enc.fit_blocks(m, m, d, bm, b)
+                assert enc.tile_bytes(tm, tk, td) <= VMEM_BUDGET_BYTES
+    # and the models are monotone in each tile dim
+    assert agg.tile_bytes(8, 512, 512) < agg.tile_bytes(8, 512, 1024)
+    assert agg.tile_bytes(8, 256, 512) < agg.tile_bytes(8, 512, 512)
+    assert enc.tile_bytes(128, 512, 256) < enc.tile_bytes(256, 512, 256)
+    assert enc.tile_bytes(128, 256, 256) < enc.tile_bytes(128, 512, 256)
+    # a shape no legal tile fits is refused before lowering, by name
+    with pytest.raises(ValueError, match="F=100000"):
+        agg.fit_blocks(100_000, 512, 546_484, 2048)
 
 
 # ---------------------------------------------------------------------------
@@ -331,7 +388,7 @@ def test_tune_handles_tiny_shapes():
     # candidates clamp exactly like the kernels; a shape smaller than every
     # candidate must still tune (regression: empty-candidate crash)
     r = autotune.tune_mule_agg(2, 8, 64, reps=1)
-    assert r["block_d"] == 128                 # max(128, d=64)
+    assert r["block_d"] == 64                  # the whole D
     e = autotune.tune_encounter_mix(16, 32, reps=1)
-    assert (e["block_m"], e["block_d"]) == (16, 128)
+    assert (e["block_m"], e["block_d"]) == (16, 32)
     assert e["speedup_vs_default"] >= 0

@@ -25,6 +25,7 @@ def _setup(m, d, seed=0, n_areas=2, p_active=1.0):
     (33, 130, 16, 128),         # ragged M and D (padding on both axes)
     (64, 1024, 64, 256),        # several d blocks
     (7, 5, 8, 128),             # smaller than one tile
+    (1100, 200, 256, 128),      # three peer tiles, the last ragged
 ])
 @pytest.mark.parametrize("p_active", [1.0, 0.6])
 def test_pallas_matches_ref(m, d, block_m, block_d, p_active):

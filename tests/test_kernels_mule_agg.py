@@ -29,6 +29,24 @@ def test_pallas_matches_ref(f, m, d, block_d, dtype):
                                np.asarray(ref, np.float32), atol=tol, rtol=tol)
 
 
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_pallas_accumulates_over_mule_tiles(dtype):
+    # M=1100 is three mule tiles of the reduction, the last one ragged: the
+    # f32 accumulator must carry across them and the interpreter's NaN
+    # padding past M must be masked out. Rows are normalized as the engine's
+    # dwell weights are, so values stay O(1) and the f32 tolerance holds.
+    f, m, d = 4, 1100, 300
+    k1, k2 = jax.random.split(jax.random.PRNGKey(1))
+    assign = jax.random.uniform(k1, (f, m), jnp.float32)
+    assign = assign / assign.sum(1, keepdims=True)
+    w = jax.random.normal(k2, (m, d), dtype)
+    ref = mule_agg_reference(assign, w)
+    out = mule_agg_pallas(assign, w, block_d=128, interpret=True)
+    tol = 1e-5 if dtype == jnp.float32 else 5e-2
+    np.testing.assert_allclose(np.asarray(out, np.float32),
+                               np.asarray(ref, np.float32), atol=tol, rtol=tol)
+
+
 @settings(max_examples=25, deadline=None)
 @given(f=st.integers(1, 6), m=st.integers(1, 12), d=st.integers(1, 64),
        seed=st.integers(0, 10_000))

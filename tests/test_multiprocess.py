@@ -108,7 +108,6 @@ def test_put_global_single_process_roundtrip():
 def test_ordered_psum_matches_psum_on_one_shard():
     """Where the rank-order fold is trivial (one shard) the deterministic
     reduction must be bitwise the raw ``lax.psum`` it replaced."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     from repro.core.distributed import ordered_pmean, ordered_psum
@@ -120,9 +119,9 @@ def test_ordered_psum_matches_psum_on_one_shard():
         return (ordered_psum(v, "data"), jax.lax.psum(v, "data"),
                 ordered_pmean(v, "data"), jax.lax.pmean(v, "data"))
 
-    a, b, c, d = jax.jit(shard_map(
+    a, b, c, d = jax.jit(jax.shard_map(
         both, mesh=mesh, in_specs=(P("data"),),
-        out_specs=(P("data"),) * 4, check_rep=False))(x)
+        out_specs=(P("data"),) * 4, check_vma=False))(x)
     np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
     np.testing.assert_array_equal(np.asarray(c), np.asarray(d))
 

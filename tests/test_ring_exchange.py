@@ -164,6 +164,7 @@ def _hop_case(seed, r, v, d):
     (16, 16, 48, 16, 48),      # disjoint offsets
     (12, 20, 7, 0, 8),         # overlapping id ranges, ragged shapes
     (8, 8, 8, 24, 24),
+    (40, 1100, 20, 0, 40),     # three visiting tiles, the last ragged
 ])
 def test_hop_kernel_matches_block_oracle(r, v, d, row0, col0):
     from repro.kernels.encounter_mix.kernel import encounter_hop_pallas
