@@ -285,21 +285,23 @@ def gossip_step(models: Any, pos: jnp.ndarray, area: jnp.ndarray,
     keys ([M, 2]) — the distributed engine passes the global-split local
     slice so sharded draws match single host row for row.
     """
-    flat, spec = flatten_population(models)
-    if ring is None:
-        mixed, mass = encounter_mix(pos, area, active, flat, radius=radius,
-                                    backend=backend)
-    else:
-        mixed, mass = ring_encounter_mix(pos, area, active, flat,
-                                         radius=radius, ring=ring,
-                                         backend=backend)
-    neigh_mean = unflatten_population(mixed, spec)
-    met = (mass > 0).astype(jnp.float32)
-    models = batched_mix(models, neigh_mean, gamma * met)           # aggregate
-    if keys is None:
-        keys = jax.random.split(key, mass.shape[0])
-    trained = jax.vmap(train_fn)(models, batches, keys)             # train
-    return batched_mix(models, trained, met)                        # only on encounter
+    with jax.named_scope("mule_peer"):
+        flat, spec = flatten_population(models)
+        if ring is None:
+            mixed, mass = encounter_mix(pos, area, active, flat,
+                                        radius=radius, backend=backend)
+        else:
+            mixed, mass = ring_encounter_mix(pos, area, active, flat,
+                                             radius=radius, ring=ring,
+                                             backend=backend)
+        neigh_mean = unflatten_population(mixed, spec)
+        met = (mass > 0).astype(jnp.float32)
+        models = batched_mix(models, neigh_mean, gamma * met)       # aggregate
+    with jax.named_scope("mule_train"):
+        if keys is None:
+            keys = jax.random.split(key, mass.shape[0])
+        trained = jax.vmap(train_fn)(models, batches, keys)         # train
+        return batched_mix(models, trained, met)                    # only on encounter
 
 
 def gossip_step_dense(models: Any, pos: jnp.ndarray, area: jnp.ndarray,

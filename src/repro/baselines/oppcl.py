@@ -107,19 +107,21 @@ def oppcl_step(models: Any, pos: jnp.ndarray, area: jnp.ndarray,
     select.
     """
     m = pos.shape[0]
-    if ring is None:
-        d2 = _block_d2(pos, area, active, 0, pos, area, active, 0)
-        d2 = jnp.where(d2 <= radius ** 2, d2, jnp.inf)
-        peer = jnp.argmin(d2, axis=1)                              # [M]
-        met = jnp.isfinite(jnp.min(d2, axis=1)).astype(jnp.float32)
-        peer_batches = jax.tree.map(lambda l: l[peer], batches)    # j's data
-    else:
-        peer_batches, met = _ring_nearest_peer(pos, area, active, batches,
-                                               radius=radius, ring=ring)
+    with jax.named_scope("mule_peer"):
+        if ring is None:
+            d2 = _block_d2(pos, area, active, 0, pos, area, active, 0)
+            d2 = jnp.where(d2 <= radius ** 2, d2, jnp.inf)
+            peer = jnp.argmin(d2, axis=1)                          # [M]
+            met = jnp.isfinite(jnp.min(d2, axis=1)).astype(jnp.float32)
+            peer_batches = jax.tree.map(lambda l: l[peer], batches)  # j's data
+        else:
+            peer_batches, met = _ring_nearest_peer(
+                pos, area, active, batches, radius=radius, ring=ring)
 
     # peer j trains i's model on j's data (exchange-train), then
     # (exchange back - aggregate)
-    if keys is None:
-        keys = jax.random.split(key, m)
-    trained = jax.vmap(train_fn)(models, peer_batches, keys)
-    return batched_mix(models, trained, gamma * met)
+    with jax.named_scope("mule_train"):
+        if keys is None:
+            keys = jax.random.split(key, m)
+        trained = jax.vmap(train_fn)(models, peer_batches, keys)
+        return batched_mix(models, trained, gamma * met)

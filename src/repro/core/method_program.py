@@ -122,11 +122,12 @@ def compile_step(program: MethodProgram, train_fn: TrainFn,
         if program.space_exchange:
             st = population_step(st, info, batches, train_fn, cfg, key)
         if program.local_train:
-            trained = local_step(st[local_side], batches[local_bkey],
-                                 train_fn, key)
-            if local_side == "mule_models":
-                trained = apply_activity_mask(info.get("active"), trained,
-                                              st[local_side])
+            with jax.named_scope("mule_train"):
+                trained = local_step(st[local_side], batches[local_bkey],
+                                     train_fn, key)
+                if local_side == "mule_models":
+                    trained = apply_activity_mask(info.get("active"),
+                                                  trained, st[local_side])
             st = {**st, local_side: trained}
         if peer_fn is not None:
             kp = (key if program.peer_key_fold is None
@@ -140,8 +141,9 @@ def compile_step(program: MethodProgram, train_fn: TrainFn,
                 return apply_activity_mask(act, new, models)
 
             k = program.peer_every
-            models = jax.lax.cond(info["t"] % k == k - 1, exchange,
-                                  lambda m: m, st["mule_models"])
+            with jax.named_scope("mule_peer"):
+                models = jax.lax.cond(info["t"] % k == k - 1, exchange,
+                                      lambda m: m, st["mule_models"])
             st = {**st, "mule_models": models}
         return st
 
@@ -202,20 +204,22 @@ def compile_distributed_step(program: MethodProgram, train_fn: Callable,
         if space_step is not None:
             st = space_step(st, info, batches, key)
         if program.local_train:
-            if cfg.mode == "fixed":
-                keys = jax.random.split(key, cfg.n_fixed)
-                trained = jax.vmap(train_fn)(st["fixed_models"],
-                                             batches["fixed"], keys)
-                st = {**st, "fixed_models": trained}
-            else:
-                m_loc = info["fixed_id"].shape[0]
-                mb = jax.tree.map(lambda l: _local_block(dcfg, l, m_loc),
-                                  batches["mule"])
-                keys = _mule_train_keys(dcfg, key, m_loc)
-                trained = jax.vmap(train_fn)(st["mule_models"], mb, keys)
-                trained = apply_activity_mask(info.get("active"), trained,
-                                              st["mule_models"])
-                st = {**st, "mule_models": trained}
+            with jax.named_scope("mule_train"):
+                if cfg.mode == "fixed":
+                    keys = jax.random.split(key, cfg.n_fixed)
+                    trained = jax.vmap(train_fn)(st["fixed_models"],
+                                                 batches["fixed"], keys)
+                    st = {**st, "fixed_models": trained}
+                else:
+                    m_loc = info["fixed_id"].shape[0]
+                    mb = jax.tree.map(
+                        lambda l: _local_block(dcfg, l, m_loc),
+                        batches["mule"])
+                    keys = _mule_train_keys(dcfg, key, m_loc)
+                    trained = jax.vmap(train_fn)(st["mule_models"], mb, keys)
+                    trained = apply_activity_mask(info.get("active"),
+                                                  trained, st["mule_models"])
+                    st = {**st, "mule_models": trained}
         if peer_fn is not None:
             kp = (key if program.peer_key_fold is None
                   else jax.random.fold_in(key, program.peer_key_fold))
@@ -238,8 +242,9 @@ def compile_distributed_step(program: MethodProgram, train_fn: Callable,
                 return apply_activity_mask(act, new, models)
 
             k = program.peer_every
-            models = jax.lax.cond(info["t"] % k == k - 1, exchange,
-                                  lambda m: m, st["mule_models"])
+            with jax.named_scope("mule_peer"):
+                models = jax.lax.cond(info["t"] % k == k - 1, exchange,
+                                      lambda m: m, st["mule_models"])
             st = {**st, "mule_models": models}
         return st
 
@@ -276,105 +281,120 @@ def _space_exchange_distributed(train_fn: Callable, dcfg) -> Callable:
             # freshness statistic alike) — distributed == single-host
             # under any mask by construction
             deliver = deliver & info["active"]
-        ages = t - st["mule_ts"]
-        fresh = st["fresh"]
-        thr = fresh["threshold"][jnp.maximum(fid, 0)]
-        if fcfg.stat == "median":
-            warm = fresh["count"][jnp.maximum(fid, 0)] < fcfg.warmup
-            fresh_ok = deliver & (warm | (ages <= thr))
-        else:
-            # legacy semantics preserved from the retired per-step path:
-            # meanstd carries no receipt counts, so FreshnessConfig.warmup
-            # is ignored — acceptance is the bare threshold test
-            fresh_ok = deliver & (ages <= thr)
+        with jax.named_scope("mule_fresh"):
+            ages = t - st["mule_ts"]
+            fresh = st["fresh"]
+            thr = fresh["threshold"][jnp.maximum(fid, 0)]
+            if fcfg.stat == "median":
+                warm = fresh["count"][jnp.maximum(fid, 0)] < fcfg.warmup
+                fresh_ok = deliver & (warm | (ages <= thr))
+            else:
+                # legacy semantics preserved from the retired per-step
+                # path: meanstd carries no receipt counts, so
+                # FreshnessConfig.warmup is ignored — acceptance is the
+                # bare threshold test
+                fresh_ok = deliver & (ages <= thr)
 
         # -- fused segment-reduce + ONE all-reduce ---------------------------
-        onehot = jax.nn.one_hot(jnp.maximum(fid, 0), cfg.n_fixed, axis=0)
-        a_loc = onehot * fresh_ok[None, :].astype(jnp.float32)  # [F, M_loc]
-        leaves, treedef = jax.tree.flatten(st["mule_models"])
-        shapes = [l.shape[1:] for l in leaves]
-        sizes = [int(np.prod(s)) if s else 1 for s in shapes]
-        flat = jnp.concatenate(
-            [l.reshape(m_loc, -1).astype(jnp.float32) for l in leaves]
-            + [jnp.ones((m_loc, 1), jnp.float32)], axis=1)
-        cols_a = [a_loc @ flat]                # models | counts  [F, D+1]
-        if fcfg.stat == "meanstd":
-            cols_a.append(a_loc @ jnp.stack([ages, ages ** 2], axis=1))
-        else:
-            d_loc = onehot * deliver[None, :].astype(jnp.float32)
-            bins = age_bin_onehot(ages, fcfg)                  # [M_loc, B]
-            cols_a.append(d_loc @ jnp.concatenate(
-                [bins, jnp.ones((m_loc, 1), jnp.float32)], axis=1))
-        # ordered_psum, not lax.psum: the fold order of this float payload
-        # must not depend on the backend, or multi-process runs drift ULPs
-        # off the single-process bitwise pins (integer reductions elsewhere
-        # are exact and stay raw)
-        fused = ordered_psum(jnp.concatenate(cols_a, axis=1), reduce_axes)
+        with jax.named_scope("mule_space"):
+            onehot = jax.nn.one_hot(jnp.maximum(fid, 0), cfg.n_fixed, axis=0)
+            a_loc = onehot * fresh_ok[None, :].astype(jnp.float32)  # [F, m]
+            leaves, treedef = jax.tree.flatten(st["mule_models"])
+            shapes = [l.shape[1:] for l in leaves]
+            sizes = [int(np.prod(s)) if s else 1 for s in shapes]
+            flat = jnp.concatenate(
+                [l.reshape(m_loc, -1).astype(jnp.float32) for l in leaves]
+                + [jnp.ones((m_loc, 1), jnp.float32)], axis=1)
+            cols_a = [a_loc @ flat]            # models | counts  [F, D+1]
+        with jax.named_scope("mule_fresh"):
+            if fcfg.stat == "meanstd":
+                cols_a.append(a_loc @ jnp.stack([ages, ages ** 2], axis=1))
+            else:
+                d_loc = onehot * deliver[None, :].astype(jnp.float32)
+                bins = age_bin_onehot(ages, fcfg)              # [M_loc, B]
+                cols_a.append(d_loc @ jnp.concatenate(
+                    [bins, jnp.ones((m_loc, 1), jnp.float32)], axis=1))
+        with jax.named_scope("mule_space"):
+            # ordered_psum, not lax.psum: the fold order of this float
+            # payload must not depend on the backend, or multi-process runs
+            # drift ULPs off the single-process bitwise pins (integer
+            # reductions elsewhere are exact and stay raw)
+            fused = ordered_psum(jnp.concatenate(cols_a, axis=1),
+                                 reduce_axes)
 
-        d_total = sum(sizes)
-        part_flat = fused[:, :d_total]
-        counts = fused[:, d_total]
-        has = (counts > 0).astype(jnp.float32)
-        norm = part_flat / jnp.maximum(counts, 1.0)[:, None]
-        outs, off = [], 0
-        for s, n, l in zip(shapes, sizes, leaves):
-            outs.append(norm[:, off:off + n]
-                        .reshape((cfg.n_fixed,) + s).astype(l.dtype))
-            off += n
-        agg = jax.tree.unflatten(treedef, outs)
-        gamma = (cfg.gamma / (1.0 + cfg.prox_mu)
-                 if cfg.aggregation == "prox" else cfg.gamma)
-        fixed_models = _tree_mix(st["fixed_models"], agg, gamma * has)
+            d_total = sum(sizes)
+            part_flat = fused[:, :d_total]
+            counts = fused[:, d_total]
+            has = (counts > 0).astype(jnp.float32)
+            norm = part_flat / jnp.maximum(counts, 1.0)[:, None]
+            outs, off = [], 0
+            for s, n, l in zip(shapes, sizes, leaves):
+                outs.append(norm[:, off:off + n]
+                            .reshape((cfg.n_fixed,) + s).astype(l.dtype))
+                off += n
+            agg = jax.tree.unflatten(treedef, outs)
+            gamma = (cfg.gamma / (1.0 + cfg.prox_mu)
+                     if cfg.aggregation == "prox" else cfg.gamma)
+            fixed_models = _tree_mix(st["fixed_models"], agg, gamma * has)
 
         # -- freshness threshold update --------------------------------------
-        if fcfg.stat == "median":
-            # paper semantics: every *delivered* age is pushed (accepted or
-            # not). Mule shards are replicated across pods, so a cross_pod
-            # reduce folds n_pods copies into the histogram and counts;
-            # quantiles are scale-invariant but warmup counts are not, so
-            # both are divided back down (psum of a literal is the axis
-            # size, folded at compile time — no extra collective).
-            n_rep = (jax.lax.psum(1, dcfg.pod_axis)
-                     if dcfg.pod_axis and dcfg.cross_pod else 1)
-            step_hist = fused[:, d_total + 1:-1] / n_rep
-            step_cnt = fused[:, -1] / n_rep
-            fresh = sketch_push_and_update(fresh, step_hist, step_cnt, fcfg)
-        else:
-            # legacy deviation: EMA of this step's accepted-age mean/std
-            age_sum, age_sq = fused[:, -2], fused[:, -1]
-            mean_age = age_sum / jnp.maximum(counts, 1.0)
-            var_age = jnp.maximum(
-                age_sq / jnp.maximum(counts, 1.0) - mean_age ** 2, 0.0)
-            target = mean_age + fcfg.beta * jnp.sqrt(var_age)
-            fresh = {"threshold": jnp.where(
-                counts > 0,
-                (1 - fcfg.alpha) * fresh["threshold"] + fcfg.alpha * target,
-                fresh["threshold"])}
+        with jax.named_scope("mule_fresh"):
+            if fcfg.stat == "median":
+                # paper semantics: every *delivered* age is pushed (accepted
+                # or not). Mule shards are replicated across pods, so a
+                # cross_pod reduce folds n_pods copies into the histogram
+                # and counts; quantiles are scale-invariant but warmup
+                # counts are not, so both are divided back down (psum of a
+                # literal is the axis size, folded at compile time — no
+                # extra collective).
+                n_rep = (jax.lax.psum(1, dcfg.pod_axis)
+                         if dcfg.pod_axis and dcfg.cross_pod else 1)
+                step_hist = fused[:, d_total + 1:-1] / n_rep
+                step_cnt = fused[:, -1] / n_rep
+                fresh = sketch_push_and_update(fresh, step_hist, step_cnt,
+                                               fcfg)
+            else:
+                # legacy deviation: EMA of this step's accepted-age mean/std
+                age_sum, age_sq = fused[:, -2], fused[:, -1]
+                mean_age = age_sum / jnp.maximum(counts, 1.0)
+                var_age = jnp.maximum(
+                    age_sq / jnp.maximum(counts, 1.0) - mean_age ** 2, 0.0)
+                target = mean_age + fcfg.beta * jnp.sqrt(var_age)
+                fresh = {"threshold": jnp.where(
+                    counts > 0,
+                    (1 - fcfg.alpha) * fresh["threshold"]
+                    + fcfg.alpha * target,
+                    fresh["threshold"])}
 
         # -- training + send-back (paper Fig. 2 cycles) ----------------------
         if cfg.mode == "fixed":
-            keys = jax.random.split(key, cfg.n_fixed)
-            trained = jax.vmap(train_fn)(fixed_models, batches["fixed"],
-                                         keys)
-            fixed_models = _tree_mix(fixed_models, trained, has)
+            with jax.named_scope("mule_train"):
+                keys = jax.random.split(key, cfg.n_fixed)
+                trained = jax.vmap(train_fn)(fixed_models, batches["fixed"],
+                                             keys)
+                fixed_models = _tree_mix(fixed_models, trained, has)
 
-        per_mule_fixed = jax.tree.map(
-            lambda l: l[jnp.maximum(fid, 0)], fixed_models)
-        gm = cfg.gamma * deliver.astype(jnp.float32)
-        mule_models = _tree_mix(st["mule_models"], per_mule_fixed, gm)
+        with jax.named_scope("mule_space"):
+            per_mule_fixed = jax.tree.map(
+                lambda l: l[jnp.maximum(fid, 0)], fixed_models)
+            gm = cfg.gamma * deliver.astype(jnp.float32)
+            mule_models = _tree_mix(st["mule_models"], per_mule_fixed, gm)
 
         if cfg.mode == "mobile":
-            mb = jax.tree.map(lambda l: _local_block(dcfg, l, m_loc),
-                              batches["mule"])
-            keys = _mule_train_keys(dcfg, key, m_loc)
-            trained = jax.vmap(train_fn)(mule_models, mb, keys)
-            mule_models = _tree_mix(mule_models, trained,
-                                    deliver.astype(jnp.float32))
+            with jax.named_scope("mule_train"):
+                mb = jax.tree.map(lambda l: _local_block(dcfg, l, m_loc),
+                                  batches["mule"])
+                keys = _mule_train_keys(dcfg, key, m_loc)
+                trained = jax.vmap(train_fn)(mule_models, mb, keys)
+                mule_models = _tree_mix(mule_models, trained,
+                                        deliver.astype(jnp.float32))
 
+        with jax.named_scope("mule_space"):
+            mule_ts = jnp.where(deliver, t, st["mule_ts"])
         return {
             "mule_models": mule_models,
             "fixed_models": fixed_models,
-            "mule_ts": jnp.where(deliver, t, st["mule_ts"]),
+            "mule_ts": mule_ts,
             "fresh": fresh,
             "t": t + 1.0,
         }

@@ -112,6 +112,10 @@ def population_step(state: Dict[str, Any], info: Dict[str, jnp.ndarray],
     nothing, and (mobile mode) does not train — every per-mule effect of
     the protocol is already gated on ``deliver``, so folding the mask into
     it covers the whole cycle.
+
+    The step's layers carry ``jax.named_scope`` names (``mule_fresh``,
+    ``mule_space``, ``mule_train``), which reach the compiled program's
+    ``op_name`` metadata; a profiler trace is split by layer on them.
     """
     t = state["t"]
     fid = info["fixed_id"]
@@ -120,37 +124,48 @@ def population_step(state: Dict[str, Any], info: Dict[str, jnp.ndarray],
         deliver = deliver & info["active"]
 
     # -- 1–2: deliver + freshness filter ------------------------------------
-    ages = t - state["mule_ts"]
-    fresh_ok = accept_mask(state["fresh"], fid, ages, cfg.freshness) & deliver
+    with jax.named_scope("mule_fresh"):
+        ages = t - state["mule_ts"]
+        fresh_ok = accept_mask(state["fresh"], fid, ages,
+                               cfg.freshness) & deliver
 
     # -- 3: dwell-weighted aggregation at fixed devices ----------------------
-    assign = (jax.nn.one_hot(jnp.maximum(fid, 0), cfg.n_fixed, axis=0)
-              * fresh_ok[None, :].astype(jnp.float32))          # [F, M]
-    agg, mass = masked_group_mean(state["mule_models"], assign,
-                                  backend=cfg.agg_backend)
-    has = (mass > 0).astype(jnp.float32)
-    gamma = cfg.gamma / (1.0 + cfg.prox_mu) if cfg.aggregation == "prox" \
-        else cfg.gamma
-    fixed_models = batched_mix(state["fixed_models"], agg, gamma * has)
+    with jax.named_scope("mule_space"):
+        assign = (jax.nn.one_hot(jnp.maximum(fid, 0), cfg.n_fixed, axis=0)
+                  * fresh_ok[None, :].astype(jnp.float32))      # [F, M]
+        agg, mass = masked_group_mean(state["mule_models"], assign,
+                                      backend=cfg.agg_backend)
+        has = (mass > 0).astype(jnp.float32)
+        gamma = cfg.gamma / (1.0 + cfg.prox_mu) \
+            if cfg.aggregation == "prox" else cfg.gamma
+        fixed_models = batched_mix(state["fixed_models"], agg, gamma * has)
 
-    fresh = push_and_update(state["fresh"], fid, ages, deliver, cfg.freshness)
+    with jax.named_scope("mule_fresh"):
+        fresh = push_and_update(state["fresh"], fid, ages, deliver,
+                                cfg.freshness)
 
     # -- 4: training ----------------------------------------------------------
     if cfg.mode == "fixed":
-        keys = jax.random.split(key, cfg.n_fixed)
-        trained = jax.vmap(train_fn)(fixed_models, batches["fixed"], keys)
-        fixed_models = batched_mix(fixed_models, trained, has)  # only active devices
+        with jax.named_scope("mule_train"):
+            keys = jax.random.split(key, cfg.n_fixed)
+            trained = jax.vmap(train_fn)(fixed_models, batches["fixed"], keys)
+            fixed_models = batched_mix(fixed_models, trained, has)  # only active devices
     # -- 5: send back to mules ------------------------------------------------
-    per_mule_fixed = jax.tree.map(lambda l: l[jnp.maximum(fid, 0)], fixed_models)
-    gm = cfg.gamma * deliver.astype(jnp.float32)
-    mule_models = batched_mix(state["mule_models"], per_mule_fixed, gm)
+    with jax.named_scope("mule_space"):
+        per_mule_fixed = jax.tree.map(lambda l: l[jnp.maximum(fid, 0)],
+                                      fixed_models)
+        gm = cfg.gamma * deliver.astype(jnp.float32)
+        mule_models = batched_mix(state["mule_models"], per_mule_fixed, gm)
 
     if cfg.mode == "mobile":
-        keys = jax.random.split(key, cfg.n_mules)
-        trained = jax.vmap(train_fn)(mule_models, batches["mule"], keys)
-        mule_models = batched_mix(mule_models, trained, deliver.astype(jnp.float32))
+        with jax.named_scope("mule_train"):
+            keys = jax.random.split(key, cfg.n_mules)
+            trained = jax.vmap(train_fn)(mule_models, batches["mule"], keys)
+            mule_models = batched_mix(mule_models, trained,
+                                      deliver.astype(jnp.float32))
 
-    mule_ts = jnp.where(deliver, t, state["mule_ts"])
+    with jax.named_scope("mule_space"):
+        mule_ts = jnp.where(deliver, t, state["mule_ts"])
     return {
         "mule_models": mule_models,
         "fixed_models": fixed_models,

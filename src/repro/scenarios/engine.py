@@ -222,8 +222,9 @@ def _scan_core(state, last, fid, exch, pos, area, act, ts, stacked_batches,
         if dynamic:
             (t,) = rest
             kb, ks = jax.random.split(jax.random.fold_in(key, t))
-            bt = (batch_fn(kb, t, context) if has_context
-                  else batch_fn(kb, t))
+            with jax.named_scope("mule_train"):
+                bt = (batch_fn(kb, t, context) if has_context
+                      else batch_fn(kb, t))
         else:
             t, bt = rest
             ks = jax.random.fold_in(key, t)
@@ -347,7 +348,8 @@ def _build_chunk_replay(generator, batches: Any, train_fn: TrainFn,
         _STATS["traces"] += 1          # python side effect: fires per trace
         ts = jnp.asarray(t0, jnp.int32) + jnp.arange(chunk_len,
                                                      dtype=jnp.int32)
-        co = generator.expand(gen_arrays, None, t0, chunk_len)
+        with jax.named_scope("mule_expand"):
+            co = generator.expand(gen_arrays, None, t0, chunk_len)
         step_fn = step_builder(co["area"])
         out = _scan_core(state, last, co["fixed_id"], co["exchange"],
                          co["pos"], co["area"], co["active"], ts,
@@ -632,6 +634,13 @@ def run_population_streamed(state: Dict[str, Any], generator, batches: Any,
     == materialized) holds across every swap because the trigger depends
     only on the area schedule, never on pruning or model state.
 
+    Profiler spans: each chunk's executable lookup and dispatch runs under
+    a ``mule/chunk`` host span (stats ``t0``, ``steps``), each re-bucket
+    check and swap under ``mule/rebucket``; inside the compiled chunk,
+    ``mule_expand`` names the generator's expand and the step's own scopes
+    name the rest (``mule_train``, ``mule_fresh``, ``mule_space``,
+    ``mule_peer``).
+
     Everything else (batches/eval/method/context contracts, the returned
     ``(final_state, aux)``) matches ``run_population`` — and so do the
     results: a streamed replay is bitwise-equal to the materialized engine
@@ -704,68 +713,72 @@ def run_population_streamed(state: Dict[str, Any], generator, batches: Any,
         batch_specs = in_specs[5] if rebucket else in_specs[4]
     for t0 in range(0, n_steps, chunk_len):
         cl = min(chunk_len, n_steps - t0)
-        stacked_chunk = (None if dynamic else
-                         jax.tree.map(lambda l: l[t0:t0 + cl], batches))
-        t0_dev = jnp.asarray(t0, jnp.int32)
-        if multiproc:
-            t0_dev = put_global(t0_dev, mesh, P())
-            if stacked_chunk is not None:
-                stacked_chunk = put_global_tree(stacked_chunk, mesh,
-                                                batch_specs)
-        fn = get_compiled_chunk_replay(
-            state, generator, gen_arrays, batches, context, key, train_fn,
-            pcfg, method=method, eval_every=eval_every, eval_fn=eval_fn,
-            chunk_len=cl, stacked_chunk=stacked_chunk, donate=donate,
-            mesh=mesh, dcfg=dcfg, rebucket=rebucket)
-        if rebucket:
-            state, last, drift, area_last, ev = fn(
-                state, last, t0_dev, gen_arrays,
-                bucket_area, stacked_chunk, context, key)
-        else:
-            state, last, ev = fn(state, last, t0_dev,
-                                 gen_arrays, stacked_chunk, context, key)
+        # host span: the executable lookup and dispatch of one chunk, on
+        # the profiler's clock beside the device planes
+        with jax.profiler.TraceAnnotation("mule/chunk", t0=t0, steps=cl):
+            stacked_chunk = (None if dynamic else
+                             jax.tree.map(lambda l: l[t0:t0 + cl], batches))
+            t0_dev = jnp.asarray(t0, jnp.int32)
+            if multiproc:
+                t0_dev = put_global(t0_dev, mesh, P())
+                if stacked_chunk is not None:
+                    stacked_chunk = put_global_tree(stacked_chunk, mesh,
+                                                    batch_specs)
+            fn = get_compiled_chunk_replay(
+                state, generator, gen_arrays, batches, context, key,
+                train_fn, pcfg, method=method, eval_every=eval_every,
+                eval_fn=eval_fn, chunk_len=cl, stacked_chunk=stacked_chunk,
+                donate=donate, mesh=mesh, dcfg=dcfg, rebucket=rebucket)
+            if rebucket:
+                state, last, drift, area_last, ev = fn(
+                    state, last, t0_dev, gen_arrays,
+                    bucket_area, stacked_chunk, context, key)
+            else:
+                state, last, ev = fn(state, last, t0_dev,
+                                     gen_arrays, stacked_chunk, context, key)
         if ev is not None:
             evals_chunks.append(ev)
         t_end = t0 + cl
         if rebucket and t_end % rb == 0 and t_end < n_steps:
-            rb_aux["checks"] += 1
-            # drift is replicated; multi-process arrays span devices that
-            # np.asarray refuses, so read this process's replica
-            d = float(drift) if not multiproc else \
-                float(host_replicated(drift))
-            rb_aux["drift"].append(d)
-            if d > threshold:
-                # the bucket order comes out of a compiled exact-int psum
-                # + replicated stable argsort (multi-host safe: the [M]
-                # area vector is sharded across processes, so no single
-                # host could np.argsort it) — bitwise the same decision
-                # as the former host-side np.argsort(kind="stable")
-                order_r, area_r = global_bucket_order(
-                    area_last, mesh, dcfg.data_axis)
-                if multiproc:
-                    order = host_replicated(order_r)
-                    area_now = host_replicated(area_r)
-                else:
-                    order = np.asarray(order_r)
-                    area_now = np.asarray(area_r)
-                if not np.array_equal(order, np.arange(n_mules)):
-                    state = reorder_mule_state(state, order)
-                    last = _take_rows(last, order)
-                    gen_arrays = reorder_generator_arrays(
-                        generator, gen_arrays, order)
-                    if not dynamic:
-                        batches = {
-                            k: (jax.tree.map(
-                                lambda l: _take_cols(l, order), v)
-                                if k == "mule" else v)
-                            for k, v in batches.items()}
-                    rb_aux["order"] = rb_aux["order"][order]
-                    rb_aux["swaps"] += 1
-                # the current area in the (possibly) new layout is the
-                # baseline the next drift check measures against
-                bucket_area = jnp.asarray(area_now[order], jnp.int32)
-                if multiproc:
-                    bucket_area = put_global(bucket_area, mesh, P(ax))
+            with jax.profiler.TraceAnnotation("mule/rebucket", t=t_end):
+                rb_aux["checks"] += 1
+                # drift is replicated; multi-process arrays span devices that
+                # np.asarray refuses, so read this process's replica
+                d = float(drift) if not multiproc else \
+                    float(host_replicated(drift))
+                rb_aux["drift"].append(d)
+                if d > threshold:
+                    # the bucket order comes out of a compiled exact-int psum
+                    # + replicated stable argsort (multi-host safe: the [M]
+                    # area vector is sharded across processes, so no single
+                    # host could np.argsort it) — bitwise the same decision
+                    # as the former host-side np.argsort(kind="stable")
+                    order_r, area_r = global_bucket_order(
+                        area_last, mesh, dcfg.data_axis)
+                    if multiproc:
+                        order = host_replicated(order_r)
+                        area_now = host_replicated(area_r)
+                    else:
+                        order = np.asarray(order_r)
+                        area_now = np.asarray(area_r)
+                    if not np.array_equal(order, np.arange(n_mules)):
+                        state = reorder_mule_state(state, order)
+                        last = _take_rows(last, order)
+                        gen_arrays = reorder_generator_arrays(
+                            generator, gen_arrays, order)
+                        if not dynamic:
+                            batches = {
+                                k: (jax.tree.map(
+                                    lambda l: _take_cols(l, order), v)
+                                    if k == "mule" else v)
+                                for k, v in batches.items()}
+                        rb_aux["order"] = rb_aux["order"][order]
+                        rb_aux["swaps"] += 1
+                    # the current area in the (possibly) new layout is the
+                    # baseline the next drift check measures against
+                    bucket_area = jnp.asarray(area_now[order], jnp.int32)
+                    if multiproc:
+                        bucket_area = put_global(bucket_area, mesh, P(ax))
     n_ev = n_steps // eval_every if (eval_fn is not None and eval_every) else 0
     steps = (np.arange(n_ev) + 1) * eval_every - 1 if n_ev else \
         np.zeros((0,), int)
