@@ -95,23 +95,80 @@ def _conv1d(x, w, b, stride):
     return out + b
 
 
+def _lstm_project(xs, wx, b):
+    """``xs Wx + b`` for every step at once: one [T*B, F] x [F, 4H]
+    contraction whose rows run time-major, so each step's rows lie
+    together."""
+    t, batch, f = xs.shape
+    return (xs.reshape(t * batch, f) @ wx + b).reshape(t, batch, -1)
+
+
+def _lstm_last_h_fwd(xs, wx, wh, b):
+    """The recurrence over ``xs`` [T, B, F] from zero state: the final
+    hidden state and what the backward pass reads, ``[h_{t-1}, c_{t-1}]``
+    stacked as one [T, B, 2H] and the four gate activations as one
+    [T, B, 4H]. ``h`` and ``c`` share a stack so that each trip writes one
+    lane-dense row (2H = 128 at the paper's widths): saved alone, ``h`` was
+    kept in the layout of the ``dWh`` contraction and cost a strided write
+    on every trip."""
+    def step(carry, xw_t):
+        h, c = carry
+        i, f, g, o = jnp.split(xw_t + h @ wh, 4, axis=-1)
+        i, f, g, o = (jax.nn.sigmoid(i), jax.nn.sigmoid(f + 1.0), jnp.tanh(g),
+                      jax.nn.sigmoid(o))
+        c_new = f * c + i * g
+        return (o * jnp.tanh(c_new), c_new), (
+            jnp.concatenate([h, c], -1), jnp.concatenate([i, f, g, o], -1))
+
+    h0 = jnp.zeros(xs.shape[1:-1] + wh.shape[:1], xs.dtype)
+    (h, _), (hc, acts) = jax.lax.scan(step, (h0, h0),
+                                      _lstm_project(xs, wx, b))
+    return h, (xs, wx, wh, hc, acts)
+
+
+@jax.custom_vjp
+def _lstm_last_h(xs, wx, wh, b):
+    """Final hidden state of the LSTM over ``xs`` [T, B, F]; the stacks the
+    forward keeps for the backward are dead code here."""
+    return _lstm_last_h_fwd(xs, wx, wh, b)[0]
+
+
+def _lstm_last_h_bwd(res, dh):
+    """A reverse loop that carries only ``(dh, dc)`` and emits each step's
+    gate gradient ``dz``; the weight, bias and input gradients are then one
+    contraction each over time and batch, outside the loop."""
+    xs, wx, wh, hc, acts = res
+    hidden = wh.shape[0]
+
+    def step(carry, saved):
+        dh, dc = carry
+        hc_t, a = saved
+        c = hc_t[..., hidden:]
+        i, f, g, o = jnp.split(a, 4, axis=-1)
+        tc = jnp.tanh(f * c + i * g)
+        dc = dc + dh * o * (1.0 - tc * tc)
+        dz = jnp.concatenate([dc * g * i * (1.0 - i),
+                              dc * c * f * (1.0 - f),
+                              dc * i * (1.0 - g * g),
+                              dh * tc * o * (1.0 - o)], -1)
+        return (dz @ wh.T, dc * f), dz
+
+    _, dz = jax.lax.scan(step, (dh, jnp.zeros_like(dh)), (hc, acts),
+                         reverse=True)
+    return (dz @ wx.T, jnp.einsum("tbf,tbg->fg", xs, dz),
+            jnp.einsum("tbh,tbg->hg", hc[..., :hidden], dz),
+            dz.sum(axis=(0, 1)))
+
+
+_lstm_last_h.defvjp(_lstm_last_h_fwd, _lstm_last_h_bwd)
+
+
 def lstm_cnn_forward(params, x):
     """x: [B, T, C] IMU window -> logits [B, n_classes]."""
     h1 = jax.nn.relu(_conv1d(x, params["conv1"], params["conv1_b"], 2))
     h2 = jax.nn.relu(_conv1d(h1, params["conv2"], params["conv2_b"], 2))
-    b, t, f = h2.shape
-    hidden = params["lstm_wh"].shape[0]
-
-    def lstm_step(carry, xt):
-        h, c = carry
-        gates = xt @ params["lstm_wx"] + h @ params["lstm_wh"] + params["lstm_b"]
-        i, f_, g, o = jnp.split(gates, 4, axis=-1)
-        c = jax.nn.sigmoid(f_ + 1.0) * c + jax.nn.sigmoid(i) * jnp.tanh(g)
-        h = jax.nn.sigmoid(o) * jnp.tanh(c)
-        return (h, c), None
-
-    h0 = jnp.zeros((b, hidden))
-    (h, _), _ = jax.lax.scan(lstm_step, (h0, h0), jnp.moveaxis(h2, 1, 0))
+    h = _lstm_last_h(jnp.moveaxis(h2, 1, 0), params["lstm_wx"],
+                     params["lstm_wh"], params["lstm_b"])
     return h @ params["fc"] + params["fc_b"]
 
 
