@@ -74,7 +74,7 @@ def readings(cell, seed: int, stand_ins: bool, program_precision=None):
     import compare
     import program as prog_mod
     import schedule
-    from reference import Population, xent
+    from reference import Population, loss_of
 
     devices = jax.devices()[:cell.chips]
     tr = cell.traffic
@@ -128,10 +128,10 @@ def readings(cell, seed: int, stand_ins: bool, program_precision=None):
             r_fresh, mule0, fixed0)
         del c_mule, c_fixed
         lr, half = cell.config["lr"], cell.config["batch"] // 2
-        hi = jax.lax.Precision.HIGHEST
+        hi, loss = jax.lax.Precision.HIGHEST, loss_of(ref)
 
         def half_sgd(p, x, y):
-            g = jax.grad(lambda q: xent(ref.forward(q, x[:half], hi),
+            g = jax.grad(lambda q: loss(ref.forward(q, x[:half], hi),
                                         y[:half]))(p)
             return jax.tree.map(lambda a, b: a - lr * b, p, g)
 

@@ -23,6 +23,7 @@ program without scopes.
 from __future__ import annotations
 
 import contextlib
+import functools
 import json
 import os
 import re
@@ -41,17 +42,23 @@ METRICS = {"local_train_ms_per_step": "mule_train",
            "peer_ms_per_step": "mule_peer",
            "expand_ms_per_step": "mule_expand",
            "unscoped_ms_per_step": None}
-_LAYER = re.compile(r"(?<!\w)(" + "|".join(LAYERS) + r")(?!\w)")
 
 
-def layer_of(scope: str) -> Optional[str]:
-    """The last layer scope in an ``op_name`` (the innermost one entered),
-    or None."""
-    found = _LAYER.findall(scope)
+@functools.lru_cache(maxsize=None)
+def _pattern(names: Tuple[str, ...]):
+    return re.compile(r"(?<!\w)(" + "|".join(map(re.escape, names))
+                      + r")(?!\w)")
+
+
+def layer_of(scope: str, names: Tuple[str, ...] = LAYERS) -> Optional[str]:
+    """The last of ``names`` in an ``op_name`` (the innermost scope
+    entered), or None."""
+    found = _pattern(tuple(names)).findall(scope)
     return found[-1] if found else None
 
 
-def layer_times(ops: List[Dict], t0: float, t1: float
+def layer_times(ops: List[Dict], t0: float, t1: float,
+                names: Tuple[str, ...] = LAYERS
                 ) -> Dict[Optional[str], float]:
     """One chip's ``[t0, t1]`` by layer (None: unscoped), by a sweep over
     the nested operations: each instant goes to the innermost operation
@@ -74,7 +81,7 @@ def layer_times(ops: List[Dict], t0: float, t1: float
             charge(*stack.pop())
         outer = stack[-1][0] if stack else None
         charge(outer, e["start"])
-        own = layer_of(e["scope"])
+        own = layer_of(e["scope"], names)
         stack.append((outer if own is None else own, e["start"] + e["dur"]))
     while stack:
         charge(*stack.pop())
@@ -82,21 +89,24 @@ def layer_times(ops: List[Dict], t0: float, t1: float
     return out
 
 
-def split(events: List[Dict]) -> Dict:
+def split(events: List[Dict], names: Tuple[str, ...] = LAYERS) -> Dict:
     """``window_s``, ``layers`` (seconds by layer) and ``unscoped_s`` of
     ``devtrace.load``'s events, averaged over chips; empty without
-    operations."""
+    operations. ``names`` are the layer scopes: a model that names scopes
+    of its own (``LAYERS + ("mule_ssm",)``) has their time taken out of
+    the scopes they nest in."""
     ops = [e for e in events if e["chip"] >= 0 and not e.get("async")]
     if not ops:
         return {}
     chips = sorted({e["chip"] for e in ops})
     t0 = min(e["start"] for e in ops)
     t1 = max(e["start"] + e["dur"] for e in ops)
-    layers = {k: 0.0 for k in {layer_of(e["scope"]) for e in ops} - {None}}
+    layers = {k: 0.0 for k in {layer_of(e["scope"], names) for e in ops}
+              - {None}}
     unscoped = 0.0
     for c in chips:
         for k, v in layer_times([e for e in ops if e["chip"] == c],
-                                t0, t1).items():
+                                t0, t1, names).items():
             if k is None:
                 unscoped += v
             else:

@@ -35,8 +35,11 @@ class Inputs:
 
 def make_inputs(cell, ref, seed: int, device=None) -> Inputs:
     """Dataset, per-mule pools and the replay key, on the device in one
-    jitted call. A mule's pool holds ``pool_images`` samples of the classes
-    its home space sees (``classes_per_place`` consecutive classes)."""
+    jitted call. The configuration's ``make_data`` returns ``n_classes``
+    groups of ``per_class`` rows, ordered by group, in any dtype and with
+    any label shape (a class per image, a next token per position). A
+    mule's pool holds ``pool_images`` rows of the groups its home space
+    sees (``classes_per_place`` consecutive groups)."""
     from schedule import commuter_draws
     names = ("weights", "data", "schedule", "replay")
     seeds = dict(zip(names, sub_seeds(seed, len(names))))

@@ -23,7 +23,9 @@ The batch of step ``t`` is the benchmark's draw with
 ``split(fold_in(key, t))[0]``: the engine's documented key discipline.
 Freshness runs on the host in NumPy; the models on the device, with every
 contraction at ``precision`` and every array in ``dtype`` (float32 at
-``highest`` for the reference; bfloat16 for its control). Mules train in
+``highest`` for the reference; bfloat16 for its control); integer inputs,
+such as token ids, stay as they are. The loss is the reference file's
+``loss(logits, y)`` where it defines one, else ``xent``. Mules train in
 blocks of ``block`` so that the reference fits beside nothing else.
 """
 from __future__ import annotations
@@ -38,9 +40,22 @@ from schedule import commuter_rows
 
 
 def xent(logits, y):
+    """Mean cross-entropy of logits [B, C] against one label per example
+    [B]. Other shapes raise: a configuration whose model gives other logits
+    defines ``loss(logits, y)`` in its reference file."""
+    if logits.ndim != 2 or y.shape != logits.shape[:1]:
+        raise ValueError(f"xent takes logits [B, C] and labels [B], not "
+                         f"{logits.shape} and {y.shape}: the reference file "
+                         f"gives the loss of other shapes as loss(logits, y)")
     z = logits - logits.max(-1, keepdims=True)
     logp = z - jnp.log(jnp.exp(z).sum(-1, keepdims=True))
     return -jnp.take_along_axis(logp, y[:, None], axis=-1).mean()
+
+
+def loss_of(ref) -> Callable:
+    """The configuration's reference loss: its file's ``loss``, else
+    ``xent``."""
+    return getattr(ref, "loss", xent)
 
 
 def _median(vals: np.ndarray) -> np.float32:
@@ -126,18 +141,20 @@ class Population:
         self.cell, self.tr, self.cfg = cell, cell.traffic, cell.config
         self.dtype, self.precision = dtype, precision
         self.draws, self.key = draws, key
-        self.ctx = {"x": context["x"].astype(dtype), "y": context["y"],
-                    "pools": context["pools"]}
+        x = context["x"]
+        if jnp.issubdtype(x.dtype, jnp.floating):
+            x = x.astype(dtype)
+        self.ctx = {"x": x, "y": context["y"], "pools": context["pools"]}
         m = self.tr["mules"]
         self.block = min(block, m)
         if m % self.block:
             raise ValueError(f"{m} mules do not split into blocks of "
                              f"{self.block}")
         lr, batch = self.cfg["lr"], self.cfg["batch"]
-        fwd = ref.forward
+        fwd, loss = ref.forward, loss_of(ref)
 
         def sgd(p, x, y):
-            g = jax.grad(lambda q: xent(fwd(q, x, precision)
+            g = jax.grad(lambda q: loss(fwd(q, x, precision)
                                         .astype(jnp.float32), y))(p)
             return jax.tree.map(lambda a, b: a - jnp.asarray(lr, dtype) * b,
                                 p, g)

@@ -33,7 +33,15 @@ One run:
    (``compare.py``) against ``bench/limits/<cell>.json``.
 
 With ``--trace 1`` the window runs under the profiler, and the per-layer
-metrics are read from that trace by ``bench/metrics/<metric>.py``.
+metrics are read from that trace by ``bench/metrics/<metric>.py``: each
+reader's ``read(ctx)`` returns its value, or None where it finds nothing to
+read. ``ctx`` holds ``events`` (``devtrace.load``'s), ``summary``
+(``devtrace.summarize``'s), ``layers`` (``bench/layers.py``'s split by the
+program's scopes, in ms per step), ``steps`` (the window's), ``trained``
+(per step, the mules whose training it keeps, by ``schedule.py``),
+``required_flops`` (``work.py``'s count over those), ``peak`` (the chip's,
+``peaks.py``) and ``cell`` (``cell.reference`` is the configuration's
+reference file, where its own counts live).
 """
 from __future__ import annotations
 
@@ -240,6 +248,7 @@ def run(argv: Optional[List[str]] = None, log=print) -> Dict[str, Any]:
             "setup_s": {"value": setup_s, "unit": "s"}}
     else:
         import devtrace as tr_mod
+        import layers
         events = tr_mod.load(tr_mod.find_xplane(trace_dir),
                              tr_mod.hlo_scopes(hlo_text))
         summary = tr_mod.summarize(events, n_steps)
@@ -247,7 +256,9 @@ def run(argv: Optional[List[str]] = None, log=print) -> Dict[str, Any]:
         ctx = {"summary": summary, "cell": cell,
                "peak": peak(dev.device_kind),
                "required_flops": float(sum(work.step_train_flops(
-                   cell.config, int(k)) for k in trained))}
+                   cell.config, int(k), ref) for k in trained)),
+               "events": events, "steps": n_steps, "trained": trained,
+               "layers": layers.per_step(layers.split(events), n_steps)}
         metrics = {}
         for m in cell.per_layer:
             reader = spec.load_module(os.path.join(BENCH_DIR, "metrics",

@@ -64,7 +64,7 @@ def small_cell(name):
     return cell
 
 
-def _run(cell, monkeypatch, tmp_path, train_fn=None):
+def _run(cell, monkeypatch, tmp_path, train_fn=None, trace=0):
     from repro.scenarios import jit_cache_clear
     jit_cache_clear()
     monkeypatch.setattr(bench_run, "CACHE_DIR", str(tmp_path))
@@ -77,7 +77,7 @@ def _run(cell, monkeypatch, tmp_path, train_fn=None):
                             dataclasses.replace(real(c, inputs),
                                                 train_fn=train_fn))
     out = bench_run.run(["--workload", cell.name, "--seed", "2147483659",
-                         "--seconds", "0.1", "--trace", "0"],
+                         "--seconds", "0.1", "--trace", str(trace)],
                         log=lambda *a, **k: None)
     json.dumps(out)
     return out

@@ -8,6 +8,11 @@ pooling) is left out, as it is in the usual MFU, and so are the taps of a
 "SAME" convolution that fall on its zero padding. Training one example is
 the forward pass, the weight gradient of every layer (as much again), and
 the input gradient of every layer but the first, whose input is data.
+
+A configuration this file does not count states its own: its reference
+file (the configuration's ``reference``) defines ``forward_flops(cfg)`` and
+``train_flops(cfg)``, FLOPs of one example by the same rules, and every
+count here takes that module as ``ref``.
 """
 from __future__ import annotations
 
@@ -52,24 +57,30 @@ def layers(cfg: Dict) -> List[Tuple[str, int]]:
         return _cnn_layers(cfg)
     if "lstm_hidden" in cfg:
         return _lstm_cnn_layers(cfg)
-    raise ValueError(f"no FLOP count for configuration {cfg.get('name')!r}")
+    raise ValueError(f"no FLOP count for configuration {cfg.get('name')!r}: "
+                     f"its reference file defines no forward_flops(cfg) "
+                     f"and train_flops(cfg)")
 
 
-def forward_flops(cfg: Dict) -> int:
+def forward_flops(cfg: Dict, ref=None) -> int:
     """FLOPs of one example's forward pass."""
+    if hasattr(ref, "forward_flops"):
+        return int(ref.forward_flops(cfg))
     return 2 * sum(macs for _, macs in layers(cfg))
 
 
-def train_flops(cfg: Dict) -> int:
+def train_flops(cfg: Dict, ref=None) -> int:
     """FLOPs of one example's forward and backward pass."""
+    if hasattr(ref, "train_flops"):
+        return int(ref.train_flops(cfg))
     ls = layers(cfg)
     fwd = 2 * sum(m for _, m in ls)
     return fwd + fwd + (fwd - 2 * ls[0][1])
 
 
-def step_train_flops(cfg: Dict, trained_mules: int) -> int:
+def step_train_flops(cfg: Dict, trained_mules: int, ref=None) -> int:
     """FLOPs a step requires: the kept mules, each on its batch."""
-    return trained_mules * cfg["batch"] * train_flops(cfg)
+    return trained_mules * cfg["batch"] * train_flops(cfg, ref)
 
 
 def space_aggregation(n_fixed: int, n_mules: int, d: int) -> Dict[str, int]:
