@@ -130,8 +130,8 @@ def readings(cell, seed: int, stand_ins: bool, program_precision=None):
         lr, half = cell.config["lr"], cell.config["batch"] // 2
         hi, loss = jax.lax.Precision.HIGHEST, loss_of(ref)
 
-        def half_sgd(p, x, y):
-            g = jax.grad(lambda q: loss(ref.forward(q, x[:half], hi),
+        def half_sgd(p, x, y, *shared):
+            g = jax.grad(lambda q: loss(ref.forward(q, x[:half], hi, *shared),
                                         y[:half]))(p)
             return jax.tree.map(lambda a, b: a - lr * b, p, g)
 

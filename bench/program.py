@@ -7,10 +7,20 @@ benchmark that imports the program (``repro``), apart from the run's use of
 What the benchmark makes itself and hands in: the weights (``weights``),
 the dataset and the mules' pools (``Inputs``), the batch draw and the SGD
 step around the program's forward pass and loss.
+
+Frozen shared weights: a reference file that defines ``init_shared(key,
+cfg)`` has a base that every mule reads and none trains. It is built once,
+on the device, into ``Inputs.context["shared"]``; ``init(key, cfg)`` gives
+the trainable leaves only, which are all that the mules and the spaces hold,
+exchange and carry. The program's forward is then ``forward(params, x,
+shared)``, and the SGD step reads the base from the replay's context
+(``bind_context``). A configuration without ``init_shared`` runs exactly
+the code it ran before.
 """
 from __future__ import annotations
 
 import dataclasses
+import functools
 import importlib
 from typing import Any, Callable, Dict
 
@@ -29,8 +39,19 @@ def _resolve(dotted: str) -> Callable:
 class Inputs:
     """Everything one run feeds the program, made from ``--seed``."""
     seeds: Dict[str, int]
-    context: Dict[str, Any]          # dataset + pools, device arrays
+    context: Dict[str, Any]          # dataset + pools (+ the frozen base)
     key: Any                         # the replay's PRNG key
+
+
+# ``weights`` splits its key in two (mules, spaces): the frozen base takes
+# the next fold of the same key, so no existing draw changes
+SHARED_FOLD = 2
+
+
+def shared_key(seeds: Dict[str, int]):
+    """The key of the frozen base, derived from the ``weights`` sub-seed."""
+    return jax.random.fold_in(jax.random.PRNGKey(seeds["weights"]),
+                              SHARED_FOLD)
 
 
 def make_inputs(cell, ref, seed: int, device=None) -> Inputs:
@@ -39,7 +60,9 @@ def make_inputs(cell, ref, seed: int, device=None) -> Inputs:
     groups of ``per_class`` rows, ordered by group, in any dtype and with
     any label shape (a class per image, a next token per position). A
     mule's pool holds ``pool_images`` rows of the groups its home space
-    sees (``classes_per_place`` consecutive groups)."""
+    sees (``classes_per_place`` consecutive groups). Where the reference
+    file defines ``init_shared``, the frozen base is built in one more
+    jitted call into ``context["shared"]``: once, not per mule or space."""
     from schedule import commuter_draws
     names = ("weights", "data", "schedule", "replay")
     seeds = dict(zip(names, sub_seeds(seed, len(names))))
@@ -61,6 +84,9 @@ def make_inputs(cell, ref, seed: int, device=None) -> Inputs:
 
     with jax.default_device(device or jax.devices()[0]):
         context = jax.jit(build)(jax.random.PRNGKey(seeds["data"]), home)
+        if hasattr(ref, "init_shared"):
+            context["shared"] = jax.jit(
+                lambda k: ref.init_shared(k, cfg))(shared_key(seeds))
         key = jax.random.PRNGKey(seeds["replay"])
     return Inputs(seeds, context, key)
 
@@ -73,7 +99,8 @@ def batch_index(key, pools, batch: int):
 
 
 def weights(cell, ref, inputs: Inputs, device=None):
-    """Mule and fixed-space weights from the seed, one jitted call."""
+    """Mule and fixed-space weights from the seed, one jitted call: the
+    trainable leaves (``ref.init``), never the frozen base."""
     tr = cell.traffic
     k = jax.random.PRNGKey(inputs.seeds["weights"])
 
@@ -87,22 +114,57 @@ def weights(cell, ref, inputs: Inputs, device=None):
         return jax.jit(build)(k)
 
 
+def bind_context(train_fn: Callable, batch_fn: Callable):
+    """The engine's ``(params, batch, key)`` train step and its batch draw
+    for a train function that also reads the replay's context,
+    ``train_fn(params, batch, key, context)``.
+
+    The engine calls the batch draw with the context inside the compiled
+    step, before that step's train step, in the same trace (``_scan_core``;
+    under ``shard_map`` the context is replicated). The draw hands that
+    traced context on, and the train step closes over it: the engine's
+    ``vmap`` over mules sees it unbatched, so every mule reads the one copy
+    that the replay takes as an argument. A train step traced without a
+    draw before it raises."""
+    seen = []
+
+    def batch(key, t, ctx):
+        seen[:] = [ctx]
+        return batch_fn(key, t, ctx)
+
+    def train(params, b, key):
+        return train_fn(params, b, key, seen[0])
+
+    return train, batch
+
+
 @dataclasses.dataclass
 class Program:
     pcfg: Any
     generator: Any
-    train_fn: Callable
+    train_fn: Callable               # (params, batch, key[, context])
     batch_fn: Callable
     method: str
     chunk_len: int
     mesh: Any = None                 # the mule mesh of a sharded cell
     dcfg: Any = None                 # its DistributedConfig
+    with_context: bool = False       # train_fn takes the replay's context
+
+    @functools.cached_property
+    def engine_fns(self):
+        """The train step and batch draw the engine is given: ``train_fn``
+        and ``batch_fn`` themselves, or bound to the context. One pair per
+        program, so the engine's cache of compiled replays hits."""
+        if not self.with_context:
+            return self.train_fn, self.batch_fn
+        return bind_context(self.train_fn, self.batch_fn)
 
     def replay(self, state, inputs: Inputs, key, n_steps: int):
         """One call of the engine's streamed entry point."""
         from repro.scenarios import run_population_streamed
+        train_fn, batch_fn = self.engine_fns
         return run_population_streamed(
-            state, self.generator, self.batch_fn, self.train_fn, self.pcfg,
+            state, self.generator, batch_fn, train_fn, self.pcfg,
             key, n_steps=n_steps, chunk_len=self.chunk_len,
             method=self.method, context=inputs.context, donate=True,
             mesh=self.mesh, dcfg=self.dcfg)
@@ -112,10 +174,11 @@ class Program:
         the window ran (lowered again, so compiled again or read from the
         cache), for its memory analysis and its HLO text."""
         from repro.scenarios.engine import get_compiled_chunk_replay
+        train_fn, batch_fn = self.engine_fns
         gen_arrays = self.generator.arrays()
         fn = get_compiled_chunk_replay(
-            state, self.generator, gen_arrays, self.batch_fn,
-            inputs.context, key, self.train_fn, self.pcfg,
+            state, self.generator, gen_arrays, batch_fn,
+            inputs.context, key, train_fn, self.pcfg,
             method=self.method, eval_every=None, eval_fn=None,
             chunk_len=self.chunk_len, donate=True, mesh=self.mesh,
             dcfg=self.dcfg)
@@ -160,10 +223,14 @@ def make_program(cell, inputs: Inputs) -> Program:
     fwd = _resolve(cfg["program"]["forward"])
     loss = _resolve(cfg["program"]["loss"])
     lr, batch = cfg["lr"], cfg["batch"]
+    with_context = "shared" in inputs.context
 
-    def sgd(params, b, key):
+    def sgd(params, b, key, *ctx):
+        """With a frozen base, ``ctx`` is the replay's context; the gradient
+        is of the trainable leaves only, the base a constant of the step."""
         xb, yb = b
-        g = jax.grad(lambda p: loss(fwd(p, xb), yb))(params)
+        shared = [c["shared"] for c in ctx]
+        g = jax.grad(lambda p: loss(fwd(p, xb, *shared), yb))(params)
         return jax.tree.map(lambda p, gg: p - lr * gg, params, g)
 
     def batch_fn(key, t, ctx):
@@ -185,5 +252,5 @@ def make_program(cell, inputs: Inputs) -> Program:
         from repro.launch.mesh import make_mule_mesh
         mesh = make_mule_mesh(tr["mesh"]["pod"], tr["mesh"]["data"])
         dcfg = DistributedConfig(pop=pcfg)
-    return Program(pcfg, gen, sgd, batch_fn,
-                   tr["method"]["name"], tr["chunk_len"], mesh, dcfg)
+    return Program(pcfg, gen, sgd, batch_fn, tr["method"]["name"],
+                   tr["chunk_len"], mesh, dcfg, with_context)

@@ -27,6 +27,12 @@ contraction at ``precision`` and every array in ``dtype`` (float32 at
 such as token ids, stay as they are. The loss is the reference file's
 ``loss(logits, y)`` where it defines one, else ``xent``. Mules train in
 blocks of ``block`` so that the reference fits beside nothing else.
+
+A configuration with frozen shared weights (its reference file defines
+``init_shared``) has them in ``context["shared"]``: every mule's SGD step
+reads that one base, cast to ``dtype`` like the models, through
+``forward(params, x, precision, shared)``, and differentiates with respect
+to its own trainable leaves only.
 """
 from __future__ import annotations
 
@@ -132,8 +138,9 @@ def _mix(a, b, g):
 
 
 class Population:
-    """Replays a cell's first steps. ``train`` may replace the SGD step of
-    one mule (the fault readings plant theirs there)."""
+    """Replays a cell's first steps. ``train(p, x, y[, shared])`` may
+    replace the SGD step of one mule (the fault readings plant theirs
+    there); it is given the base where the configuration has one."""
 
     def __init__(self, cell, ref, context, key, draws, *, dtype=jnp.float32,
                  precision=jax.lax.Precision.HIGHEST, block: int = 128,
@@ -141,10 +148,13 @@ class Population:
         self.cell, self.tr, self.cfg = cell, cell.traffic, cell.config
         self.dtype, self.precision = dtype, precision
         self.draws, self.key = draws, key
-        x = context["x"]
-        if jnp.issubdtype(x.dtype, jnp.floating):
-            x = x.astype(dtype)
-        self.ctx = {"x": x, "y": context["y"], "pools": context["pools"]}
+        cast = lambda l: (l.astype(dtype) if jnp.issubdtype(l.dtype,
+                                                              jnp.floating)
+                          else l)
+        self.ctx = {"x": cast(context["x"]), "y": context["y"],
+                    "pools": context["pools"]}
+        self.shared = (jax.tree.map(cast, context["shared"])
+                       if "shared" in context else None)
         m = self.tr["mules"]
         self.block = min(block, m)
         if m % self.block:
@@ -153,8 +163,8 @@ class Population:
         lr, batch = self.cfg["lr"], self.cfg["batch"]
         fwd, loss = ref.forward, loss_of(ref)
 
-        def sgd(p, x, y):
-            g = jax.grad(lambda q: loss(fwd(q, x, precision)
+        def sgd(p, x, y, *shared):
+            g = jax.grad(lambda q: loss(fwd(q, x, precision, *shared)
                                         .astype(jnp.float32), y))(p)
             return jax.tree.map(lambda a, b: a - jnp.asarray(lr, dtype) * b,
                                 p, g)
@@ -171,20 +181,24 @@ class Population:
         kb = jax.random.split(jax.random.fold_in(self.key, t))[0]
         return batch_index(kb, self.ctx["pools"], self.batch)
 
-    def _train_all(self, models, idx, x, y):
-        """SGD step of every mule, ``block`` mules at a time."""
+    def _train_all(self, models, idx, x, y, shared):
+        """SGD step of every mule, ``block`` mules at a time; every mule
+        reads the one ``shared`` base."""
         m, b = idx.shape[0], self.block
         blocks = jax.tree.map(lambda l: l.reshape((m // b, b) + l.shape[1:]),
                               models)
+        train = (self.train if shared is None else
+                 lambda p, xx, yy: self.train(p, xx, yy, shared))
 
         def one(args):
             p, i = args
-            return jax.vmap(self.train)(p, x[i], y[i])
+            return jax.vmap(train)(p, x[i], y[i])
 
         out = jax.lax.map(one, (blocks, idx.reshape(m // b, b, -1)))
         return jax.tree.map(lambda l: l.reshape((m,) + l.shape[2:]), out)
 
-    def _device_step(self, mule, fixed, fid, accept, deliver, idx, x, y):
+    def _device_step(self, mule, fixed, fid, accept, deliver, idx, x, y,
+                     shared):
         n_fixed = self.tr["spaces"]
         g = self.dtype(self.tr["gamma"])
         f = jnp.maximum(fid, 0)
@@ -198,10 +212,10 @@ class Population:
         fixed = _mix(fixed, agg, g * has)
         d = deliver.astype(self.dtype)
         mule = _mix(mule, jax.tree.map(lambda l: l[f], fixed), g * d)
-        mule = _mix(mule, self._train_all(mule, idx, x, y), d)
+        mule = _mix(mule, self._train_all(mule, idx, x, y, shared), d)
         return mule, fixed
 
-    def _gossip_step(self, mule, area, active, pos, idx, x, y):
+    def _gossip_step(self, mule, area, active, pos, idx, x, y, shared):
         meth = self.tr["method"]
         d2 = ((pos[:, None] - pos[None]) ** 2).sum(-1)
         enc = ((area[:, None] == area[None]) & (d2 <= meth["radius"] ** 2)
@@ -213,7 +227,7 @@ class Population:
             "mn,n...->m...", norm, l, precision=self.precision), mule)
         met = (mass > 0).astype(self.dtype)
         mule = _mix(mule, mixed, self.dtype(meth["gamma"]) * met)
-        return _mix(mule, self._train_all(mule, idx, x, y), met)
+        return _mix(mule, self._train_all(mule, idx, x, y, shared), met)
 
     # -- replay ------------------------------------------------------------
 
@@ -237,13 +251,13 @@ class Population:
                 accept = fresh.accept(fid, ages, deliver)
                 fresh.push(fid, ages, deliver)
                 mule, fixed = self._step(mule, fixed, fid, accept, deliver,
-                                         idx, x, y)
+                                         idx, x, y, self.shared)
                 mule_ts = np.where(deliver, np.float32(t), mule_ts)
             elif name == "gossip":
                 every = tr["method"]["peer_every"]
                 if t % every == every - 1:
                     mule = self._gossip(mule, r["area"], act, r["pos"][0],
-                                        idx, x, y)
+                                        idx, x, y, self.shared)
             else:
                 raise ValueError(f"no reference for method {name!r}")
         return mule, fixed, fresh.state()

@@ -8,8 +8,11 @@ step, as shapes only), compiles it with the TPU compiler for one chip of a
 ``v5e:2x2`` topology (for a cell on a mule mesh, for the mesh over its
 chips, giving the bytes on each), and
 prints one JSON line per population size: the bytes ``memory_analysis``
-gives (arguments, outputs, temporaries, aliased) against the chip's 16 GB.
-A size that does not fit fails to compile and says so.
+gives (arguments, outputs, temporaries, aliased) against the chip's 16 GB,
+and of the arguments, the population state's (``state_bytes``: models,
+timestamps, freshness) and the frozen base's (``shared_bytes``, for a
+configuration with ``init_shared``: one copy among the arguments, whatever
+the mules). A size that does not fit fails to compile and says so.
 """
 from __future__ import annotations
 
@@ -72,12 +75,22 @@ def compile_chunk(cell, topo):
     t0 = place(jax.ShapeDtypeStruct((), jnp.int32))
     ctx = place(jax.eval_shape(lambda: inputs.context))
     key = place(jax.eval_shape(lambda: inputs.key))
+    train_fn, batch_fn = prog.engine_fns
     fn = get_compiled_chunk_replay(
-        state, prog.generator, gen_arrays, prog.batch_fn, ctx, key,
-        prog.train_fn, prog.pcfg, method=prog.method, eval_every=None,
+        state, prog.generator, gen_arrays, batch_fn, ctx, key,
+        train_fn, prog.pcfg, method=prog.method, eval_every=None,
         eval_fn=None, chunk_len=prog.chunk_len, donate=True, mesh=prog.mesh,
         dcfg=prog.dcfg)
-    return fn.lower(state, last, t0, gen_arrays, None, ctx, key).compile()
+    compiled = fn.lower(state, last, t0, gen_arrays, None, ctx,
+                        key).compile()
+    return compiled, {"state_bytes": _bytes(state),
+                      "shared_bytes": _bytes(ctx.get("shared"))}
+
+
+def _bytes(tree) -> int:
+    """Bytes of a tree's leaves, whole (not per chip)."""
+    import jax
+    return sum(l.size * l.dtype.itemsize for l in jax.tree.leaves(tree))
 
 
 def main(argv=None) -> int:
@@ -96,7 +109,9 @@ def main(argv=None) -> int:
         cell.traffic = dict(cell.traffic, mules=m)
         row = {"workload": cell.name, "mules": m}
         try:
-            ma = compile_chunk(cell, topo).memory_analysis()
+            compiled, parts = compile_chunk(cell, topo)
+            ma = compiled.memory_analysis()
+            row.update(parts)
             row.update({k: int(getattr(ma, k)) for k in (
                 "argument_size_in_bytes", "output_size_in_bytes",
                 "temp_size_in_bytes", "alias_size_in_bytes",

@@ -1,6 +1,6 @@
 """FLOP counts from the shapes against XLA's count of one forward pass
 (CPU), for every configuration of ``BENCHMARK.json`` at its own widths and
-the test-only token configuration. XLA also counts the elementwise work
+the two test-only token configurations. XLA also counts the elementwise work
 (batch norm, activations, pooling, LSTM gates) that the counts leave out,
 so its count lies a few percent above, never below: at most 8% above,
 unless the configuration states its own share (``xla_flops_over``) and the
@@ -32,8 +32,9 @@ def _configs():
                        if w["config"] == c["name"])
         out.append((c["name"], os.path.join(ROOT, c["file"]),
                     os.path.join(BENCH_DIR, "traffic", traffic + ".json")))
-    return out + [("stub-token", os.path.join(STUB, "stub-token.json"),
-                   os.path.join(STUB, "mlmule-commuter-m16.json"))]
+    return out + [(name, os.path.join(STUB, name + ".json"),
+                   os.path.join(STUB, "mlmule-commuter-m16.json"))
+                  for name in ("stub-token", "stub-lora")]
 
 
 CONFIGS = _configs()
@@ -43,7 +44,8 @@ CONFIGS = _configs()
                          ids=[c[0] for c in CONFIGS])
 def test_forward_flops_against_xla(name, config, traffic):
     """The example is one row of the configuration's own data, made as its
-    first cell's traffic makes it."""
+    first cell's traffic makes it; a configuration with a frozen base reads
+    it as its last argument."""
     cfg = _json(config)
     ref = load_module(os.path.join(ROOT, cfg["reference"]))
     params = ref.init(jax.random.PRNGKey(0), cfg)
@@ -51,8 +53,10 @@ def test_forward_flops_against_xla(name, config, traffic):
         == cfg["params_per_mule"]
     x, _ = ref.make_data(jax.random.PRNGKey(0), cfg,
                          dict(_json(traffic)["data"], per_class=1))
-    cost = jax.jit(lambda p, x: ref.forward(p, x, None)).lower(
-        params, x[:1]).compile().cost_analysis()
+    shared = (ref.init_shared(jax.random.PRNGKey(1), cfg),) \
+        if hasattr(ref, "init_shared") else ()
+    cost = jax.jit(lambda p, x, *s: ref.forward(p, x, None, *s)).lower(
+        params, x[:1], *shared).compile().cost_analysis()
     cost = cost[0] if isinstance(cost, list) else cost
     over = cfg.get("xla_flops_over", 0.08)
     if "xla_flops_over" in cfg:

@@ -13,6 +13,15 @@ A configuration this file does not count states its own: its reference
 file (the configuration's ``reference``) defines ``forward_flops(cfg)`` and
 ``train_flops(cfg)``, FLOPs of one example by the same rules, and every
 count here takes that module as ``ref``.
+
+Frozen weights (a reference file's ``init_shared``) take no weight
+gradient. Training one example over a frozen base is the forward pass, the
+weight gradient of every contraction with a trained weight (an adapter),
+and the input gradient of every contraction whose input depends on a
+trained leaf: what autodiff with respect to the trained leaves computes.
+Past the first adapter that is every later layer's input gradient, so a
+deep model over a frozen base costs about forward plus input gradient,
+twice its forward pass.
 """
 from __future__ import annotations
 
