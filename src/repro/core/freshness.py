@@ -14,9 +14,11 @@ our documented assumption. History is a fixed ring buffer per device.
 Two statistics back the threshold:
 
 - the exact ring buffer (``init_freshness`` / ``push_and_update``) — the
-  single-host engine's path. The ring push is a sequential scan over mules
-  (slot order matters), which is NOT associative and therefore cannot be
-  merged with a ``psum`` across population shards.
+  single-host engine's path. The push keeps the serial ring's slot order
+  (mule order within a step) through each receipt's rank among the same
+  step's receipts to its device, computed for all mules at once. A push
+  split across population shards would still need that rank as a prefix
+  count across shards, so the ring is not merged with a ``psum``.
 - an associative histogram sketch (``init_freshness_sketch`` /
   ``sketch_push_and_update``) — ages are binned into a fixed per-device
   histogram; per-step shard contributions are plain sums, so the
@@ -92,38 +94,47 @@ def push_and_update(state, fixed_ids: jnp.ndarray, ages: jnp.ndarray,
     """Push delivered ages into per-device rings, then update thresholds.
 
     fixed_ids/ages/deliver: [M] per-mule target, age, delivering-this-step.
-    Sequential scan over mules keeps the ring semantics exact for multiple
-    deliveries to one device in the same step.
+    The rings come out as if the mules pushed one at a time in mule order:
+    a receipt's rank ``r`` among this step's receipts to its device ``f``
+    puts it at slot ``(count[f] + r) % K``, and of ``n`` receipts only the
+    last ``K`` ranks survive (each earlier one is overwritten by the
+    receipt ``K`` ranks after it). The survivors of one device hold
+    distinct slots, so the write is a select over the [M, F, K] slot
+    one-hot with no order among writes. Ages move as their int32 bits and
+    ranks are integer prefix counts: nothing in the push rounds.
     """
-    def push(carry, inp):
-        ages_buf, count = carry
-        fid, age, dlv = inp
+    k = cfg.history
+    n_fixed = state["count"].shape[0]
+    d = deliver & (fixed_ids >= 0)
+    hit = d[:, None] & (jnp.maximum(fixed_ids, 0)[:, None]
+                        == jnp.arange(n_fixed)[None, :])          # [M, F]
+    hit_i = hit.astype(jnp.int32)
+    rank = jnp.cumsum(hit_i, axis=0) - hit_i      # same-device receipts before
+    n = jnp.sum(hit_i, axis=0)                                    # [F]
+    keep = hit & (rank >= n - k)
+    slot = (state["count"] + rank) % k
+    sel = keep[:, :, None] & (slot[:, :, None] == jnp.arange(k))  # [M, F, K]
+    bits = jax.lax.bitcast_convert_type(ages.astype(jnp.float32), jnp.int32)
+    new_bits = jnp.sum(jnp.where(sel, bits[:, None, None], 0), axis=0)
+    ages_buf = jnp.where(
+        jnp.any(sel, axis=0),
+        jax.lax.bitcast_convert_type(new_bits, jnp.float32), state["ages"])
+    return {"ages": ages_buf, "count": state["count"] + n,
+            "threshold": update_threshold(state["threshold"], ages_buf, cfg)}
 
-        def do(args):
-            ages_buf, count = args
-            f = jnp.maximum(fid, 0)
-            slot = count[f] % cfg.history
-            ages_buf = ages_buf.at[f, slot].set(age)
-            count = count.at[f].add(1)
-            return ages_buf, count
 
-        carry = jax.lax.cond(dlv & (fid >= 0), do, lambda a: a, (ages_buf, count))
-        return carry, None
-
-    (ages_buf, count), _ = jax.lax.scan(
-        push, (state["ages"], state["count"]),
-        (fixed_ids, ages.astype(jnp.float32), deliver))
-
+def update_threshold(threshold: jnp.ndarray, ages_buf: jnp.ndarray,
+                     cfg: FreshnessConfig) -> jnp.ndarray:
+    """One EMA step of each device's threshold toward median + beta * MAD
+    of its ring ``ages_buf`` [F, K]; a device with an empty ring keeps its
+    threshold."""
     valid = ages_buf < INF
     med = _masked_median(ages_buf, valid)
     mad = _masked_median(jnp.abs(ages_buf - med[:, None]), valid)
     target = med + cfg.beta * mad
-    any_hist = jnp.any(valid, axis=-1)
-    new_thr = jnp.where(
-        any_hist,
-        (1 - cfg.alpha) * state["threshold"] + cfg.alpha * target,
-        state["threshold"])
-    return {"ages": ages_buf, "count": count, "threshold": new_thr}
+    return jnp.where(jnp.any(valid, axis=-1),
+                     (1 - cfg.alpha) * threshold + cfg.alpha * target,
+                     threshold)
 
 
 # ---------------------------------------------------------------------------

@@ -7,8 +7,9 @@ operation only by its instruction and the benchmark's trace reduction
 charges device time by the last ``mule_*`` scope of that metadata. Pinned
 here on tiny widths, single-host and on a mesh over the suite's host
 devices: each scope appears, every contraction, loop and conditional of
-the step lies under one, the freshness push's loop under ``mule_fresh``,
-and the scopes change nothing but metadata. The streamed replay's host
+the step lies under one, the freshness push's ring write (a select over
+mules, spaces and ring slots, with no loop) under ``mule_fresh``, and the
+scopes change nothing but metadata. The streamed replay's host
 span ``mule/chunk`` is read back from a profiler trace, one per chunk.
 """
 import contextlib
@@ -166,12 +167,30 @@ def test_heavy_instructions_of_the_step_are_scoped(hlo, method,
 
 
 def test_freshness_push_loop_is_under_mule_fresh(hlo):
-    """The single-host push is a serial loop over mules directly under
-    ``mule_fresh`` (the step's other loops on the CPU are rolled threefry
-    rounds of its random draws)."""
-    loops = [o for _, c, o in _in_step(_instructions(hlo[("mlmule", False)]))
-             if c == "while"]
-    assert [o for o in loops if o.endswith("/mule_fresh/while")]
+    """The single-host push runs no loop: no ``while`` lies under
+    ``mule_fresh``, and its ring write, a select over the [M, F, K] slot
+    one-hot, does."""
+    ins = _instructions(hlo[("mlmule", False)])
+    assert not [o for _, c, o in ins
+                if c == "while" and _layer(o) == "mule_fresh"]
+    n_mules, ring = 16, FreshnessConfig().history
+    writes = [o for shape, o in _selects(hlo[("mlmule", False)])
+              if _layer(o) == "mule_fresh"
+              and np.prod(shape) == n_mules * F * ring]
+    assert writes
+
+
+def _selects(text):
+    """(shape, op_name) of every ``select`` instruction with metadata."""
+    out = []
+    for line in text.splitlines():
+        m = _INSTR.match(line)
+        sel = m and re.match(r"\w+\[([\d,]*)\]\S* select\(", m.group(2))
+        op = sel and _OP_NAME.search(m.group(2))
+        if op:
+            out.append(([int(d) for d in sel.group(1).split(",") if d],
+                        op.group(1)))
+    return out
 
 
 def _strip(text):
