@@ -244,18 +244,20 @@ def run_sweep_bench(n_seeds: int = 8, steps: int = 300, n_mules: int = 20,
 
 def run_donation_bench(steps: int = 300, n_mules: int = 20):
     """Compile-time memory effect of donating the replay's state buffers."""
-    from repro.scenarios.engine import _colocation_tensors, get_compiled_replay
+    from repro.scenarios.engine import (dense_colocation,
+                                        get_compiled_chunk_replay)
 
     pop, co, batch_fn, train_fn, pcfg = _setup(n_mules=n_mules, steps=steps)
     key = jax.random.PRNGKey(7)
-    fid, exch, pos, area, act = _colocation_tensors(co)
-    args = (pop, fid, exch, pos, area, act, None, None, key)
+    gen = dense_colocation(co)
+    args = (pop, jnp.zeros((n_mules,), jnp.int32), jnp.asarray(0, jnp.int32),
+            gen.arrays(), None, None, key)
     rows = []
     for donate in (False, True):
-        fn = get_compiled_replay(pop, fid, exch, pos, area, act, batch_fn,
-                                 None, key, train_fn, pcfg, method="mlmule",
-                                 eval_every=None, eval_fn=None,
-                                 donate=donate)
+        fn = get_compiled_chunk_replay(
+            pop, gen, gen.arrays(), batch_fn, None, key, train_fn, pcfg,
+            method="mlmule", eval_every=None, eval_fn=None, chunk_len=steps,
+            donate=donate)
         try:
             ma = fn.lower(*args).compile().memory_analysis()
             alias = int(ma.alias_size_in_bytes)

@@ -3,8 +3,9 @@
 Two halves, one artifact:
 
 **Engine roofline.** ``analyze_engine_step`` compiles the scenario-engine
-replay (``repro.scenarios.engine.get_compiled_replay`` — the exact program
-experiments run, single-host or mule-sharded) for one (method × M × mesh)
+replay (``repro.scenarios.engine.get_compiled_chunk_replay`` over the
+materialized schedule — the exact program experiments run, single-host or
+mule-sharded) for one (method × M × mesh)
 cell, feeds the compiled HLO through the scan-aware analyzer
 (``repro.launch.hlo_analysis``), and returns the three roofline terms in
 seconds (compute / memory / collective against the per-chip peaks in
@@ -311,13 +312,14 @@ def analyze_engine_step(method: str, n_mules: int = 32, steps: int = 24,
     through ``run_population_distributed``'s shard_map program instead.
     """
     import jax
+    import jax.numpy as jnp
     from repro.launch.hlo_analysis import analyze_hlo
     from repro.launch.roofline import HBM_BW, ICI_BW, PEAK_FLOPS
-    from repro.scenarios.engine import (_colocation_tensors,
-                                        get_compiled_replay)
+    from repro.scenarios.engine import (dense_colocation,
+                                        get_compiled_chunk_replay)
 
     pop, co, batch_fn, train_fn, pcfg = _engine_workload(n_mules, steps)
-    fid, exch, pos, area, act = _colocation_tensors(co)
+    gen = dense_colocation(co)
     key = jax.random.PRNGKey(7)
     if mesh is None:
         chips, mesh_name, dcfg, state = 1, "1", None, pop
@@ -328,12 +330,14 @@ def analyze_engine_step(method: str, n_mules: int = 32, steps: int = 24,
         state = to_distributed_state(pop, dcfg)
         chips = mesh.size
         mesh_name = "x".join(str(s) for s in mesh.shape.values())
-    fn = get_compiled_replay(state, fid, exch, pos, area, act, batch_fn,
-                             None, key, train_fn, pcfg, method=method,
-                             eval_every=None, eval_fn=None,
-                             mesh=mesh, dcfg=dcfg)
-    args = (state, fid, exch, pos, area, act, None, None, key)
-    compiled = fn.lower(*args).compile()
+    fn = get_compiled_chunk_replay(state, gen, gen.arrays(), batch_fn, None,
+                                   key, train_fn, pcfg, method=method,
+                                   eval_every=None, eval_fn=None,
+                                   chunk_len=steps, donate=False, mesh=mesh,
+                                   dcfg=dcfg)
+    last = jnp.zeros((n_mules,), jnp.int32)
+    compiled = fn.lower(state, last, jnp.asarray(0, jnp.int32), gen.arrays(),
+                        None, None, key).compile()
     costs = analyze_hlo(compiled.as_text())
     t_c = costs.flops / PEAK_FLOPS
     t_m = costs.bytes / HBM_BW

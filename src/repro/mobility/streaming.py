@@ -35,8 +35,12 @@ A chunk generator is an object with
   ``expand(arrays, key, t0, chunk_len)`` is the same computation with the
   array pytree passed explicitly (what the engine traces).
 
-Two families:
+Three families:
 
+- :func:`dense_colocation` wraps a materialized ``[T, M]`` colocation dict
+  as-is: ``expand`` slices it. It is how ``run_population``,
+  ``run_population_distributed`` and ``run_sweep`` reach the engine's one
+  compiled chunk replay, as a single chunk of length ``T``.
 - :func:`compact_colocation` losslessly compacts ANY materialized
   colocation dict into per-mule run-length segments and expands them
   on-device — bitwise-equal to the host tensors by construction, chunk
@@ -265,6 +269,108 @@ def compact_colocation(colocation: Dict[str, Any],
                              max_area=int(area.max(initial=0)))
 
 
+def _dev(x, dtype) -> jnp.ndarray:
+    """To-device cast that never host-round-trips an existing device array.
+
+    ``jnp.asarray(np.asarray(x))`` copies device arrays back to the host
+    and up again — double-buffering ``[T, M]`` schedules for nothing. A
+    ``jax.Array`` of the right dtype passes through untouched; the wrong
+    dtype casts on device; everything else (numpy, lists) uploads once.
+    """
+    dtype = np.dtype(dtype)
+    if isinstance(x, jax.Array):
+        return x if x.dtype == dtype else x.astype(dtype)
+    return jnp.asarray(np.asarray(x), dtype)
+
+
+def _colocation_tensors(colocation):
+    """Normalize a colocation dict to (fid, exch, pos, area, act) arrays.
+
+    ``act`` is the per-step activity (churn) mask ``[T, M]`` bool from the
+    ``"active"`` key; absent, it defaults to all-ones — the dense
+    population. Because the mask is data (same shape/dtype either way), a
+    dense and a churned run of the same schedule shape share one compiled
+    replay. Inputs already on device stay on device (no host copy). Leading
+    axes before ``[T, M]`` (a sweep's seed stack) pass through.
+    """
+    fid = _dev(colocation["fixed_id"], jnp.int32)
+    exch = _dev(colocation["exchange"], bool)
+    m = fid.shape[-1]
+    pos = colocation.get("pos")
+    pos = (jnp.zeros(fid.shape + (2,), jnp.float32) if pos is None
+           else _dev(pos, jnp.float32))
+    area = colocation.get("area")
+    area = (jnp.zeros(fid.shape[:-2] + (m,), jnp.int32) if area is None
+            else _dev(area, jnp.int32))
+    act = colocation.get("active")
+    act = (jnp.ones(fid.shape, bool) if act is None
+           else _dev(act, bool))
+    return fid, exch, pos, area, act
+
+
+_DENSE_KEYS = ("fixed_id", "exchange", "pos", "area", "active")
+
+
+class DenseColocation:
+    """A materialized ``[T, M]`` schedule as a chunk generator.
+
+    ``arrays()`` are the schedule's own tensors, on device; ``expand``
+    slices ``chunk_len`` steps at ``t0`` out of them. This is how the
+    materialized entry points (``run_population``,
+    ``run_population_distributed``, ``run_sweep``) reach the one compiled
+    chunk replay. Leading axes before ``[T, M]`` (a sweep's seed stack) are
+    vmapped away by the engine before ``expand`` sees the arrays.
+    """
+
+    def __init__(self, arrays: Dict[str, Any]):
+        self._arrays = arrays
+        fid = arrays["fixed_id"]
+        self.n_steps, self.n_mules = (int(d) for d in fid.shape[-2:])
+        self._area_dyn = arrays["area"].ndim == fid.ndim
+
+    @functools.cached_property
+    def max_area(self) -> int:
+        area = self._arrays["area"]
+        return int(jnp.max(area)) if area.size else 0
+
+    def arrays(self) -> Dict[str, Any]:
+        return self._arrays
+
+    def specs(self, axis: str):
+        """PartitionSpecs per array leaf: the mule columns shard."""
+        from jax.sharding import PartitionSpec as P
+        cols = P(None, axis)
+        return {"fixed_id": cols, "exchange": cols,
+                "pos": P(None, axis, None),
+                "area": cols if self._area_dyn else P(axis),
+                "active": cols}
+
+    def static_token(self) -> Tuple:
+        return ("dense", self._area_dyn)
+
+    def expand(self, arrays: Dict[str, Any], key, t0,
+               chunk_len: int) -> Dict[str, Any]:
+        del key
+        t0 = jnp.asarray(t0, jnp.int32)
+
+        def window(leaf):
+            return jax.lax.dynamic_slice_in_dim(leaf, t0, chunk_len, axis=0)
+
+        return {k: (arrays[k] if k == "area" and not self._area_dyn
+                    else window(arrays[k])) for k in _DENSE_KEYS}
+
+    def generate_chunk(self, key, t0, chunk_len: int) -> Dict[str, Any]:
+        return self.expand(self._arrays, key, t0, chunk_len)
+
+
+def dense_colocation(colocation: Dict[str, Any]) -> DenseColocation:
+    """Wrap a materialized colocation dict as a chunk generator (see
+    :class:`DenseColocation`). Missing ``pos``/``area``/``active`` fill in
+    as zeros, zeros and all-ones; extra keys are ignored."""
+    return DenseColocation(dict(zip(_DENSE_KEYS,
+                                    _colocation_tensors(colocation))))
+
+
 class CommuterStream:
     """Procedural counter-keyed commuter schedule: O(M) memory, any T.
 
@@ -443,9 +549,11 @@ def materialize_generator(gen, n_steps: Optional[int] = None,
                           chunk_len: int = 256) -> Dict[str, np.ndarray]:
     """Expand a chunk generator into the classic numpy colocation dict.
 
-    The O(T * M) reference path: streamed replay must be bitwise-equal to
-    ``run_population`` over this dict (the scale bench asserts it per M).
-    Includes ``init_space``/``init_area`` when the generator provides them.
+    The O(T * M) reference: a streamed replay must be bitwise-equal to
+    ``run_population`` over this dict, i.e. to the same compiled chunk
+    program over ``dense_colocation`` of it (the scale bench asserts it per
+    M). Includes ``init_space``/``init_area`` when the generator provides
+    them.
     """
     n_steps = int(gen.n_steps if n_steps is None else n_steps)
     chunks = []

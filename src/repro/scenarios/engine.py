@@ -1,66 +1,45 @@
-"""Compiled scenario engine: method-dispatched scans with a jit cache.
+"""Compiled scenario engine: one chunked replay program with a jit cache.
 
 The harness used to drive the simulation with a per-step Python loop — one
 jitted dispatch per time step, thousands of dispatches per experiment. Here
-the whole run is one (optionally chunked) ``lax.scan`` over precomputed
-``[T, M]`` co-location tensors, with periodic evaluation *inside* the scan,
-so a full scenario replay is a single XLA program. Every mobile-protocol
-method (``repro.core.population.METHODS_MOBILE``) rides the same engine:
-``method=`` selects the per-step update built by ``make_method_step`` (the
-baselines' 3-step exchange cadence is a ``lax.cond`` on the step index).
+a replay is a ``lax.scan`` over co-location chunks, with periodic
+evaluation *inside* the scan, so each chunk is a single XLA program. Every
+mobile-protocol method (``repro.core.population.METHODS_MOBILE``) rides
+the same engine: ``method=`` selects the per-step update built by
+``make_method_step`` (the baselines' 3-step exchange cadence is a
+``lax.cond`` on the step index).
 
-Distributed replay
-------------------
-``run_population_distributed`` lifts the same scan — ``psum`` collective
-schedule included — under ``shard_map`` over the mesh mule (``data``) axis:
-mule state and colocation columns shard, fixed-device state replicates, and
-``repro.core.distributed.make_distributed_method_step`` supplies the
-step, so a mule-sharded experiment is ONE program instead of one
-``shard_map`` dispatch per step (``run_population_distributed_loop``
-preserves the per-step dispatch pattern as the parity/bench reference).
-Every ``METHODS_MOBILE`` method lowers to the distributed
-step through the one ``repro.core.method_program`` table — the
-peer-encounter baselines cross shards via its ring ``ppermute``
-exchange. Multi-seed sweeps compose: ``run_sweep_distributed`` stacks the
-seed ``vmap`` axis *inside* the shard_map block (i.e. outside the mule
-axis, unsharded), one program per method, bitwise-equal per lane to
-sequential distributed runs.
-
-Streaming replay
-----------------
-``run_population`` scans a *materialized* ``[T, M]`` schedule — at
-M=10^5-10^6 the schedule dwarfs the population state.
-``run_population_streamed`` replaces the precomputed xs with a chunk
-generator (``repro.mobility.streaming``): each compiled dispatch expands
-``chunk_len`` steps of colocation *inside the trace* from O(M)-ish compact
-arrays and scans them, so schedule memory is O(chunk · M) regardless of
-horizon. Its jit cache key hashes the generator signature + chunk shape,
-never ``T`` — one compiled program serves any horizon — and state/last
-buffers are donated per chunk (``donate_argnums=(0, 1)``). Under a mesh
-the generator's arrays shard over the mule axis and each shard expands
-only its own columns: the distributed engine never gathers a global
-schedule. Parity: a streamed replay is bitwise-equal to ``run_population``
-over ``materialize_generator(generator)``, chunk boundaries included,
-because ``_scan_core`` is shared and every step keys off its *global*
-index.
+One compiled replay
+-------------------
+``_build_chunk_replay`` is the engine's only replay program. It expands
+``chunk_len`` steps of colocation from a chunk generator
+(``repro.mobility.streaming``) *inside the trace*, at global steps ``t0 ..
+t0+chunk_len``, and scans them (``_scan_core``). ``run_population_streamed``
+drives it chunk by chunk. ``run_population``, ``run_population_distributed``
+and ``run_sweep(_distributed)`` run their ``[T, M]`` schedule as one chunk
+of length ``T`` of ``dense_colocation(...)``, whose ``expand`` slices it.
+Under a mesh the program runs under ``shard_map`` over the mule (``data``)
+axis with ``make_distributed_method_step`` as the step: mule state and the
+generator's mule columns shard (each shard expands only its own columns),
+fixed-device state replicates. A sweep ``vmap``s it over a stacked seed
+axis, inside the ``shard_map`` block and outside the mule axis. Every step
+keys off its *global* index, so chunking is invisible: a streamed replay is
+bitwise-equal to ``run_population`` over ``materialize_generator(gen)``.
+The per-step loops ``run_population_loop`` and
+``run_population_distributed_loop`` are the independent references.
 
 Jit cache
 ---------
-``run_population`` used to retrace on every call — fine for one replay per
-experiment, wasteful for sweeps. Compiled replays are now memoized in a
-module-level cache keyed on everything that determines the traced program:
-
-  (kind, method, cfg, eval_every, n_steps,
-   train_fn, eval_fn, batch-callable identity,
-   shape/dtype signatures of state, colocation tensors, stacked batches,
-   context, and the PRNG key;
-   plus donation, and — for the distributed kinds — mesh and the
-   DistributedConfig)
-
-``cfg`` hashes by value (frozen dataclass); functions hash by identity, so
-reuse the *same* ``train_fn``/``batches``/``eval_fn`` objects across calls
-to hit the cache (a fresh lambda per call means a fresh trace). The cache
-holds strong references but is LRU-bounded (oldest entries evicted past
+Compiled programs are memoized in one LRU cache (``_memo``), keyed on
+everything that determines the traced program — kind (``stream`` with its
+``_distributed``/``_rebucket``/``_sweep`` variants, or the distributed
+loop's ``dist_loop_step``), method, cfg, eval_every, chunk_len, generator
+class + static_token(), train/eval/batch functions, the shape signatures
+of the arguments, donation, and mesh + DistributedConfig — never the
+horizon. ``cfg`` hashes by value (frozen dataclass); functions hash by
+identity, so reuse the *same* ``train_fn``/``batches``/``eval_fn`` objects
+across calls to hit the cache (a fresh lambda per call means a fresh
+trace). The cache holds strong references but is LRU-bounded (oldest entries evicted past
 ``_JIT_CACHE_MAX``), so loops that can never hit — e.g. a fresh closure
 per experiment — don't accumulate executables and closure-captured data
 for process lifetime; ``jit_cache_clear()`` resets it and
@@ -106,6 +85,7 @@ from collections import OrderedDict
 
 from repro.core.population import (PopulationConfig, TrainFn,
                                    make_method_step, population_step)
+from repro.mobility.streaming import _colocation_tensors, dense_colocation
 
 # LRU-bounded: callers that build fresh batch/eval closures per experiment
 # (their identity is part of the key) can never hit, so eviction caps the
@@ -113,6 +93,22 @@ from repro.core.population import (PopulationConfig, TrainFn,
 _JIT_CACHE: "OrderedDict[Any, Callable]" = OrderedDict()
 _JIT_CACHE_MAX = 32
 _STATS = {"traces": 0, "hits": 0, "misses": 0}
+
+
+def _memo(cache_key, build: Callable[[], Callable]) -> Callable:
+    """The compiled program under ``cache_key``: a hit moves it to the LRU
+    end; a miss calls ``build()``, stores the result and evicts the oldest
+    entries past ``_JIT_CACHE_MAX``."""
+    fn = _JIT_CACHE.get(cache_key)
+    if fn is not None:
+        _STATS["hits"] += 1
+        _JIT_CACHE.move_to_end(cache_key)
+        return fn
+    _STATS["misses"] += 1
+    fn = _JIT_CACHE[cache_key] = build()
+    while len(_JIT_CACHE) > _JIT_CACHE_MAX:
+        _JIT_CACHE.popitem(last=False)
+    return fn
 
 
 def jit_cache_stats(per_process: bool = False) -> Dict[str, int]:
@@ -151,52 +147,14 @@ def _sig(tree: Any) -> Any:
         (tuple(np.shape(l)), np.dtype(jnp.result_type(l)).str) for l in leaves)
 
 
-def _dev(x, dtype) -> jnp.ndarray:
-    """To-device cast that never host-round-trips an existing device array.
-
-    ``jnp.asarray(np.asarray(x))`` copies device arrays back to the host
-    and up again — double-buffering ``[T, M]`` schedules for nothing. A
-    ``jax.Array`` of the right dtype passes through untouched; the wrong
-    dtype casts on device; everything else (numpy, lists) uploads once.
-    """
-    dtype = np.dtype(dtype)
-    if isinstance(x, jax.Array):
-        return x if x.dtype == dtype else x.astype(dtype)
-    return jnp.asarray(np.asarray(x), dtype)
-
-
-def _colocation_tensors(colocation, n_steps=None):
-    """Normalize a colocation dict to (fid, exch, pos, area, act) arrays.
-
-    ``act`` is the per-step activity (churn) mask ``[T, M]`` bool from the
-    ``"active"`` key; absent, it defaults to all-ones — the dense
-    population. Because the mask is data (same shape/dtype either way), a
-    dense and a churned run of the same schedule shape share one compiled
-    replay. Inputs already on device stay on device (no host copy).
-    """
-    fid = _dev(colocation["fixed_id"], jnp.int32)
-    exch = _dev(colocation["exchange"], bool)
-    t, m = fid.shape[-2], fid.shape[-1]
-    pos = colocation.get("pos")
-    pos = (jnp.zeros(fid.shape + (2,), jnp.float32) if pos is None
-           else _dev(pos, jnp.float32))
-    area = colocation.get("area")
-    area = (jnp.zeros(fid.shape[:-2] + (m,), jnp.int32) if area is None
-            else _dev(area, jnp.int32))
-    act = colocation.get("active")
-    act = (jnp.ones(fid.shape, bool) if act is None
-           else _dev(act, bool))
-    return fid, exch, pos, area, act
-
-
 def _scan_core(state, last, fid, exch, pos, area, act, ts, stacked_batches,
                context, key, *, dynamic: bool, batch_fn, has_context: bool,
                step_fn, eval_every: Optional[int],
                eval_fn: Optional[Callable]):
     """Traceable scan over one contiguous window of the schedule.
 
-    ``ts`` carries the *global* step indices of the window (the streamed
-    path hands in ``t0 + arange(chunk)``), so the per-step
+    ``ts`` carries the *global* step indices of the window (``t0 +
+    arange(chunk)``), so the per-step
     ``fold_in(key, t)`` discipline — and with it bitwise parity against a
     full-horizon replay — is independent of how the horizon is chunked.
     ``last`` enters as carry for the same reason.
@@ -268,44 +226,6 @@ def _scan_core(state, last, fid, exch, pos, area, act, ts, stacked_batches,
     return carry[0], carry[1], evals
 
 
-def _build_replay(batches: Any, train_fn: TrainFn, cfg: PopulationConfig, *,
-                  method: str, eval_every: Optional[int],
-                  eval_fn: Optional[Callable], n_steps: int,
-                  has_context: bool,
-                  step_builder: Optional[Callable] = None) -> Callable:
-    """Un-jitted replay core ``(state, fid, exch, pos, area, stacked_batches,
-    context, key) -> (state, last_fid, evals)`` closed over the statics.
-
-    ``step_builder(area) -> step_fn`` overrides the per-step update (the
-    distributed engine injects its shard-local collective step here); the
-    default is the single-host ``make_method_step`` dispatch.
-
-    The activity mask rides the scan as one more ``[T, M]`` xs column:
-    step ``t`` hands ``act[t]`` to the method step as ``info["active"]``
-    and gates ``last_fid`` (a sleeping mule records no visit).
-    """
-    dynamic = callable(batches)
-    batch_fn = batches if dynamic else None
-    if step_builder is None:
-        step_builder = lambda area: make_method_step(method, train_fn, cfg,
-                                                     area)
-
-    def replay(state, fid, exch, pos, area, act, stacked_batches, context,
-               key):
-        _STATS["traces"] += 1          # python side effect: fires per trace
-        step_fn = step_builder(area)
-        n_mules = fid.shape[1]
-        ts = jnp.arange(n_steps, dtype=jnp.int32)
-        last = jnp.zeros((n_mules,), jnp.int32)
-        return _scan_core(state, last, fid, exch, pos, area, act, ts,
-                          stacked_batches, context, key, dynamic=dynamic,
-                          batch_fn=batch_fn, has_context=has_context,
-                          step_fn=step_fn, eval_every=eval_every,
-                          eval_fn=eval_fn)
-
-    return replay
-
-
 def _build_chunk_replay(generator, batches: Any, train_fn: TrainFn,
                         cfg: PopulationConfig, *, method: str,
                         eval_every: Optional[int],
@@ -313,17 +233,18 @@ def _build_chunk_replay(generator, batches: Any, train_fn: TrainFn,
                         has_context: bool,
                         step_builder: Optional[Callable] = None,
                         rebucket: bool = False,
-                        pmean_axis: Optional[str] = None) -> Callable:
-    """Un-jitted streamed-chunk core ``(state, last, t0, gen_arrays,
-    stacked_chunk, context, key) -> (state, last_fid, evals)``.
+                        pmean_axis: Optional[str] = None,
+                        vmapped: bool = False) -> Callable:
+    """Un-jitted chunk core ``(state, last, t0, gen_arrays, stacked_chunk,
+    context, key) -> (state, last_fid, evals)``.
 
     The colocation slice is *generated inside the trace*: the generator's
     ``expand`` runs on its array pytree (a traced input — under
     ``shard_map`` each shard holds and expands only its own mule columns)
-    at global steps ``t0 .. t0+chunk_len``, feeding the same ``_scan_core``
-    the materialized path scans. Only the generator's *static* config is
-    closed over, so one compiled program serves every same-shape chunk of
-    every same-signature generator, whatever the horizon.
+    at global steps ``t0 .. t0+chunk_len``, feeding ``_scan_core``. Only
+    the generator's *static* config is closed over, so one compiled program
+    serves every same-shape chunk of every same-signature generator,
+    whatever the horizon.
 
     ``rebucket=True`` compiles the re-bucketing variant: the signature
     grows a ``bucket_area`` input after ``gen_arrays`` (each mule's area at
@@ -333,6 +254,9 @@ def _build_chunk_replay(generator, batches: Any, train_fn: TrainFn,
     into a replicated scalar, so the trigger costs one tiny collective per
     chunk) and the end-of-chunk area vector the host driver argsorts into
     the next bucket order when the drift crosses the threshold.
+
+    ``vmapped=True`` maps it over a leading seed axis on every argument
+    but ``t0`` (``run_sweep``).
     """
     dynamic = callable(batches)
     batch_fn = batches if dynamic else None
@@ -371,148 +295,53 @@ def _build_chunk_replay(generator, batches: Any, train_fn: TrainFn,
             drift = ordered_pmean(drift, pmean_axis)
         return st, last_fid, drift, jnp.asarray(area_end, jnp.int32), evals
 
+    if vmapped:
+        return jax.vmap(chunk_replay,
+                        in_axes=(0, 0, None) + (0,) * (4 + rebucket))
     return chunk_replay
 
 
-def _distributed_specs(state, batches, dcfg, *, vmapped: bool,
-                       area_dyn: bool = False):
-    """shard_map in/out PartitionSpecs for the distributed replay.
-
-    Mule-population leaves (leading mule axis) shard over ``dcfg.data_axis``;
-    everything else replicates. With ``vmapped`` the seed stack axis is an
-    extra unsharded leading dim (the seed vmap sits *inside* the shard_map
-    block, outside the mule axis). ``area_dyn`` marks a time-varying
-    [T, M] area trace, which shards like the other colocation columns.
-    """
-    from jax.sharding import PartitionSpec as P
-    ax = dcfg.data_axis
-    lead = (None,) if vmapped else ()
-
-    def subtree(tree, spec):
-        return jax.tree.map(lambda _: spec, tree)
-
-    state_specs = {
-        k: subtree(v, P(*lead, ax) if k.startswith("mule") else P())
-        for k, v in state.items()
-    }
-    if callable(batches) or batches is None:
-        batch_specs = P()                       # no leaves to partition
-    else:
-        batch_specs = {
-            k: subtree(v, P(*lead, None, ax) if k == "mule" else P())
-            for k, v in batches.items()
-        }
-    area_spec = P(*lead, None, ax) if area_dyn else P(*lead, ax)
-    in_specs = (state_specs,
-                P(*lead, None, ax), P(*lead, None, ax),   # fid, exch
-                P(*lead, None, ax), area_spec,            # pos, area
-                P(*lead, None, ax),                       # activity mask
-                batch_specs, P(), P())                    # batches, ctx, key
-    out_specs = (state_specs, P(*lead, ax), P())          # state, last, evals
-    return in_specs, out_specs
-
-
-def get_compiled_replay(state, fid, exch, pos, area, act, batches, context,
-                        key, train_fn: TrainFn, cfg: PopulationConfig, *,
-                        method: str, eval_every: Optional[int],
-                        eval_fn: Optional[Callable],
-                        vmapped: bool = False, donate: bool = False,
-                        mesh=None, dcfg=None) -> Callable:
-    """Fetch (or build + memoize) the jitted replay for this signature.
-
-    ``vmapped=True`` wraps the core in ``jax.vmap`` over a leading stack
-    axis on every array argument (``repro.scenarios.sweep`` uses this); the
-    leading-axis difference in the shape signature keeps batched and
-    unbatched programs in separate cache slots.
-
-    ``mesh``/``dcfg`` select the distributed kind: the (possibly vmapped)
-    core is wrapped in ``shard_map`` over the mesh with the step from
-    ``make_distributed_method_step``, and both join the cache key.
-
-    ``donate=True`` donates the state pytree (``donate_argnums=(0,)``) so
-    the replay reuses its buffers in place — callers must not touch the
-    input state afterwards; parity paths that replay the same state twice
-    keep the default. Donated and undonated programs cache separately.
-    """
-    dynamic = callable(batches)
-    n_steps = int(fid.shape[-2])
-    kind = (("distributed_sweep" if vmapped else "distributed")
-            if mesh is not None else ("sweep" if vmapped else "population"))
-    cache_key = (
-        kind, method, cfg, eval_every,
-        n_steps, train_fn, eval_fn, batches if dynamic else None,
-        _sig(state), _sig((fid, exch, pos, area, act)),
-        None if dynamic else _sig(batches),
-        None if context is None else _sig(context), _sig(key),
-        donate, None if mesh is None else (mesh, dcfg),
-    )
-    fn = _JIT_CACHE.get(cache_key)
-    if fn is not None:
-        _STATS["hits"] += 1
-        _JIT_CACHE.move_to_end(cache_key)
-        return fn
-    _STATS["misses"] += 1
-    step_builder = None
-    if mesh is not None:
-        from repro.core.distributed import make_distributed_method_step
-        dist_step = make_distributed_method_step(method, train_fn, dcfg,
-                                                 mesh=mesh)
-        step_builder = lambda area: dist_step
-    core = _build_replay(batches, train_fn, cfg, method=method,
-                         eval_every=eval_every, eval_fn=eval_fn,
-                         n_steps=n_steps, has_context=context is not None,
-                         step_builder=step_builder)
-    if vmapped:
-        core = jax.vmap(core)
-    if mesh is not None:
-        in_specs, out_specs = _distributed_specs(
-            state, batches, dcfg, vmapped=vmapped,
-            area_dyn=np.ndim(area) == np.ndim(fid))
-        core = jax.shard_map(core, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    fn = jax.jit(core, donate_argnums=(0,) if donate else ())
-    _JIT_CACHE[cache_key] = fn
-    while len(_JIT_CACHE) > _JIT_CACHE_MAX:
-        _JIT_CACHE.popitem(last=False)
-    return fn
-
-
 def _streamed_specs(state, generator, batches, dcfg, *,
-                    rebucket: bool = False):
-    """shard_map in/out PartitionSpecs for the streamed chunk replay.
+                    rebucket: bool = False, vmapped: bool = False):
+    """shard_map in/out PartitionSpecs for the chunk replay.
 
     Argument order mirrors ``_build_chunk_replay``: (state, last, t0,
     gen_arrays[, bucket_area], stacked_chunk, context, key). Mule-population
     leaves and the generator's mule-leading arrays (its ``specs`` method
     knows which) shard over ``dcfg.data_axis``; ``t0``/context/key
     replicate. The re-bucketing variant adds the sharded ``bucket_area``
-    input and the ``(drift replicated, area_last sharded)`` outputs.
+    input and the ``(drift replicated, area_last sharded)`` outputs. With
+    ``vmapped`` every spec but ``t0``'s leads with the unsharded seed axis.
     """
     from jax.sharding import PartitionSpec as P
     ax = dcfg.data_axis
+    lead = (None,) if vmapped else ()
+    mules = P(*lead, ax)
 
     def subtree(tree, spec):
         return jax.tree.map(lambda _: spec, tree)
 
     state_specs = {
-        k: subtree(v, P(ax) if k.startswith("mule") else P())
+        k: subtree(v, mules if k.startswith("mule") else P())
         for k, v in state.items()
     }
     if callable(batches) or batches is None:
         batch_specs = P()
     else:
         batch_specs = {
-            k: subtree(v, P(None, ax) if k == "mule" else P())
+            k: subtree(v, P(*lead, None, ax) if k == "mule" else P())
             for k, v in batches.items()
         }
+    gen_specs = jax.tree.map(lambda s: P(*lead, *s), generator.specs(ax),
+                             is_leaf=lambda s: isinstance(s, P))
     if rebucket:
-        in_specs = (state_specs, P(ax), P(), generator.specs(ax), P(ax),
+        in_specs = (state_specs, mules, P(), gen_specs, mules,
                     batch_specs, P(), P())
-        out_specs = (state_specs, P(ax), P(), P(ax), P())
+        out_specs = (state_specs, mules, P(), mules, P())
     else:
-        in_specs = (state_specs, P(ax), P(), generator.specs(ax),
+        in_specs = (state_specs, mules, P(), gen_specs,
                     batch_specs, P(), P())
-        out_specs = (state_specs, P(ax), P())
+        out_specs = (state_specs, mules, P())
     return in_specs, out_specs
 
 
@@ -522,8 +351,9 @@ def get_compiled_chunk_replay(state, generator, gen_arrays, batches, context,
                               eval_fn: Optional[Callable], chunk_len: int,
                               stacked_chunk: Any = None, donate: bool = True,
                               mesh=None, dcfg=None,
-                              rebucket: bool = False) -> Callable:
-    """Fetch (or build + memoize) the jitted streamed-chunk replay.
+                              rebucket: bool = False,
+                              vmapped: bool = False) -> Callable:
+    """Fetch (or build + memoize) the jitted chunk replay.
 
     The cache key is deliberately **horizon-free**: it hashes the
     generator's *class + static_token() + array signature* and the chunk
@@ -533,11 +363,13 @@ def get_compiled_chunk_replay(state, generator, gen_arrays, batches, context,
     is the one extra entry). ``donate=True`` (the default here — streaming
     exists for populations too big to copy) donates *state and last_fid*
     (``donate_argnums=(0, 1)``), so the carry ping-pongs through the same
-    buffers across the whole chunk loop.
+    buffers across the whole chunk loop. ``mesh``/``dcfg`` wrap the core in
+    ``shard_map`` with the step from ``make_distributed_method_step``.
     """
     dynamic = callable(batches)
-    kind = ("stream_distributed" if mesh is not None else "stream") \
-        + ("_rebucket" if rebucket else "")
+    kind = ("stream" + ("_distributed" if mesh is not None else "")
+            + ("_rebucket" if rebucket else "")
+            + ("_sweep" if vmapped else ""))
     cache_key = (
         kind, method, cfg, eval_every, chunk_len,
         type(generator).__qualname__, generator.static_token(),
@@ -547,35 +379,30 @@ def get_compiled_chunk_replay(state, generator, gen_arrays, batches, context,
         None if context is None else _sig(context), _sig(key),
         donate, None if mesh is None else (mesh, dcfg),
     )
-    fn = _JIT_CACHE.get(cache_key)
-    if fn is not None:
-        _STATS["hits"] += 1
-        _JIT_CACHE.move_to_end(cache_key)
-        return fn
-    _STATS["misses"] += 1
-    step_builder = None
-    if mesh is not None:
-        from repro.core.distributed import make_distributed_method_step
-        dist_step = make_distributed_method_step(method, train_fn, dcfg,
-                                                 mesh=mesh)
-        step_builder = lambda area: dist_step
-    core = _build_chunk_replay(generator, batches, train_fn, cfg,
-                               method=method, eval_every=eval_every,
-                               eval_fn=eval_fn, chunk_len=chunk_len,
-                               has_context=context is not None,
-                               step_builder=step_builder, rebucket=rebucket,
-                               pmean_axis=(dcfg.data_axis
-                                           if mesh is not None else None))
-    if mesh is not None:
-        in_specs, out_specs = _streamed_specs(state, generator, batches,
-                                              dcfg, rebucket=rebucket)
-        core = jax.shard_map(core, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    fn = jax.jit(core, donate_argnums=(0, 1) if donate else ())
-    _JIT_CACHE[cache_key] = fn
-    while len(_JIT_CACHE) > _JIT_CACHE_MAX:
-        _JIT_CACHE.popitem(last=False)
-    return fn
+
+    def build():
+        step_builder = None
+        if mesh is not None:
+            from repro.core.distributed import make_distributed_method_step
+            dist_step = make_distributed_method_step(method, train_fn, dcfg,
+                                                     mesh=mesh)
+            step_builder = lambda area: dist_step
+        core = _build_chunk_replay(
+            generator, batches, train_fn, cfg, method=method,
+            eval_every=eval_every, eval_fn=eval_fn, chunk_len=chunk_len,
+            has_context=context is not None, step_builder=step_builder,
+            rebucket=rebucket,
+            pmean_axis=dcfg.data_axis if mesh is not None else None,
+            vmapped=vmapped)
+        if mesh is not None:
+            in_specs, out_specs = _streamed_specs(
+                state, generator, batches, dcfg, rebucket=rebucket,
+                vmapped=vmapped)
+            core = jax.shard_map(core, mesh=mesh, in_specs=in_specs,
+                                 out_specs=out_specs, check_vma=False)
+        return jax.jit(core, donate_argnums=(0, 1) if donate else ())
+
+    return _memo(cache_key, build)
 
 
 def run_population_streamed(state: Dict[str, Any], generator, batches: Any,
@@ -597,11 +424,11 @@ def run_population_streamed(state: Dict[str, Any], generator, batches: Any,
                any horizon). Schedule memory is O(chunk_len · M) live
                slices plus the generator's compact arrays, never O(T · M).
     n_steps:   horizon; defaults to ``generator.n_steps``.
-    chunk_len: steps generated + scanned per compiled dispatch. Must be a
-               multiple of ``eval_every`` when ``eval_fn`` is set (so
-               evals land on the same global steps as the materialized
-               engine). Bigger chunks amortize dispatch; smaller chunks
-               shrink the live schedule slice.
+    chunk_len: steps generated + scanned per compiled dispatch. When
+               ``eval_fn`` is set and the run has more than one chunk, it
+               must be a multiple of ``eval_every`` (so evals land on the
+               same global steps as in one chunk). Bigger chunks amortize
+               dispatch; smaller chunks shrink the live schedule slice.
     donate:    default **True** (unlike ``run_population``): state and
                ``last_fid`` buffers are donated each chunk and rebound,
                so the population updates in place for the whole run. Pass
@@ -647,6 +474,17 @@ def run_population_streamed(state: Dict[str, Any], generator, batches: Any,
     over ``materialize_generator(generator)``, chunk boundaries included
     (the global-step key discipline makes chunking invisible).
     """
+    return _replay(state, generator, batches, train_fn, cfg, key,
+                   n_steps=n_steps, chunk_len=chunk_len,
+                   eval_every=eval_every, eval_fn=eval_fn, method=method,
+                   context=context, donate=donate, mesh=mesh, dcfg=dcfg)
+
+
+def _replay(state, generator, batches, train_fn, cfg, key, *, n_steps,
+            chunk_len, eval_every, eval_fn, method, context, donate, mesh,
+            dcfg, vmapped: bool = False):
+    """``run_population_streamed``; ``vmapped`` stacks a seed axis on every
+    argument (``run_sweep``), whose lanes never re-bucket."""
     if mesh is not None and dcfg is None:
         raise ValueError("run_population_streamed: mesh requires dcfg")
     if key is None:
@@ -657,13 +495,14 @@ def run_population_streamed(state: Dict[str, Any], generator, batches: Any,
     n_mules = int(generator.n_mules)
     if chunk_len <= 0:
         raise ValueError(f"chunk_len={chunk_len} must be positive")
-    if eval_fn is not None and eval_every and chunk_len % eval_every:
+    if (eval_fn is not None and eval_every and chunk_len % eval_every
+            and n_steps > chunk_len):
         raise ValueError(
             f"chunk_len={chunk_len} must be a multiple of "
             f"eval_every={eval_every} so streamed evals land on the same "
-            f"global steps as the materialized engine")
-    rb = int(getattr(dcfg, "rebucket_every", 0) or 0) if dcfg is not None \
-        else 0
+            f"global steps as in one chunk")
+    rb = (int(getattr(dcfg, "rebucket_every", 0) or 0)
+          if dcfg is not None and not vmapped else 0)
     if rb > 0 and rb % chunk_len:
         raise ValueError(
             f"rebucket_every={rb} must be a multiple of "
@@ -676,7 +515,9 @@ def run_population_streamed(state: Dict[str, Any], generator, batches: Any,
         _check_mule_sharding(n_mules, mesh, dcfg)
     gen_arrays = generator.arrays()
     dynamic = callable(batches)
-    last = jnp.zeros((n_mules,), jnp.int32)
+    lanes = jax.tree.leaves(key)[0].shape[:1] if vmapped else ()
+    t_axis = len(lanes)                 # the time axis of stacked batches
+    last = jnp.zeros(lanes + (n_mules,), jnp.int32)
     evals_chunks = []
     rebucket = rb > 0
     rb_aux = None
@@ -699,25 +540,25 @@ def run_population_streamed(state: Dict[str, Any], generator, batches: Any,
         from repro.launch.multiprocess import (host_replicated, put_global,
                                                put_global_tree)
         in_specs, _ = _streamed_specs(state, generator, batches, dcfg,
-                                      rebucket=rebucket)
-        ax = dcfg.data_axis
+                                      rebucket=rebucket, vmapped=vmapped)
         state = put_global_tree(state, mesh, in_specs[0])
-        last = put_global(last, mesh, P(ax))
-        gen_arrays = put_global_tree(gen_arrays, mesh, generator.specs(ax))
+        last = put_global(last, mesh, in_specs[1])
+        gen_arrays = put_global_tree(gen_arrays, mesh, in_specs[3])
         key = put_global(key, mesh, P())
         if context is not None:
             context = put_global_tree(
                 context, mesh, jax.tree.map(lambda _: P(), context))
         if rebucket:
-            bucket_area = put_global(bucket_area, mesh, P(ax))
-        batch_specs = in_specs[5] if rebucket else in_specs[4]
+            bucket_area = put_global(bucket_area, mesh, in_specs[4])
+        batch_specs = in_specs[-3]
     for t0 in range(0, n_steps, chunk_len):
         cl = min(chunk_len, n_steps - t0)
         # host span: the executable lookup and dispatch of one chunk, on
         # the profiler's clock beside the device planes
         with jax.profiler.TraceAnnotation("mule/chunk", t0=t0, steps=cl):
-            stacked_chunk = (None if dynamic else
-                             jax.tree.map(lambda l: l[t0:t0 + cl], batches))
+            stacked_chunk = (None if dynamic else jax.tree.map(
+                lambda l: jax.lax.slice_in_dim(l, t0, t0 + cl, axis=t_axis),
+                batches))
             t0_dev = jnp.asarray(t0, jnp.int32)
             if multiproc:
                 t0_dev = put_global(t0_dev, mesh, P())
@@ -728,7 +569,8 @@ def run_population_streamed(state: Dict[str, Any], generator, batches: Any,
                 state, generator, gen_arrays, batches, context, key,
                 train_fn, pcfg, method=method, eval_every=eval_every,
                 eval_fn=eval_fn, chunk_len=cl, stacked_chunk=stacked_chunk,
-                donate=donate, mesh=mesh, dcfg=dcfg, rebucket=rebucket)
+                donate=donate, mesh=mesh, dcfg=dcfg, rebucket=rebucket,
+                vmapped=vmapped)
             if rebucket:
                 state, last, drift, area_last, ev = fn(
                     state, last, t0_dev, gen_arrays,
@@ -778,14 +620,15 @@ def run_population_streamed(state: Dict[str, Any], generator, batches: Any,
                     # baseline the next drift check measures against
                     bucket_area = jnp.asarray(area_now[order], jnp.int32)
                     if multiproc:
-                        bucket_area = put_global(bucket_area, mesh, P(ax))
+                        bucket_area = put_global(bucket_area, mesh,
+                                                 in_specs[4])
     n_ev = n_steps // eval_every if (eval_fn is not None and eval_every) else 0
     steps = (np.arange(n_ev) + 1) * eval_every - 1 if n_ev else \
         np.zeros((0,), int)
     evals = None
     if evals_chunks:
         evals = (evals_chunks[0] if len(evals_chunks) == 1 else
-                 jax.tree.map(lambda *xs: jnp.concatenate(xs, axis=0),
+                 jax.tree.map(lambda *xs: jnp.concatenate(xs, axis=t_axis),
                               *evals_chunks))
     aux = {"last_fid": last, "eval_steps": steps, "evals": evals}
     if rb_aux is not None:
@@ -832,19 +675,11 @@ def run_population(state: Dict[str, Any], colocation: Dict[str, Any],
     ``aux = {"last_fid": [M], "eval_steps": np [E], "evals": stacked/None}``
     where eval step ``i`` is taken after step ``(i+1)*eval_every - 1``.
     """
-    fid, exch, pos, area, act = _colocation_tensors(colocation)
-    n_steps = fid.shape[0]
-    stacked = None if callable(batches) else batches
-    fn = get_compiled_replay(state, fid, exch, pos, area, act, batches,
-                             context, key, train_fn, cfg, method=method,
-                             eval_every=eval_every, eval_fn=eval_fn,
-                             donate=donate)
-    state, last, evals = fn(state, fid, exch, pos, area, act, stacked,
-                            context, key)
-    n_ev = n_steps // eval_every if (eval_fn is not None and eval_every) else 0
-    steps = (np.arange(n_ev) + 1) * eval_every - 1 if n_ev else \
-        np.zeros((0,), int)
-    return state, {"last_fid": last, "eval_steps": steps, "evals": evals}
+    gen = dense_colocation(colocation)
+    return run_population_streamed(
+        state, gen, batches, train_fn, cfg, key, chunk_len=gen.n_steps,
+        eval_every=eval_every, eval_fn=eval_fn, method=method,
+        context=context, donate=donate)
 
 
 def run_population_loop(state: Dict[str, Any], colocation: Dict[str, Any],
@@ -1013,10 +848,13 @@ def run_population_distributed(state: Dict[str, Any],
     """``run_population`` with the population sharded over the mesh.
 
     The whole replay — the ``psum`` collective schedule of
-    ``make_distributed_method_step`` included — is one ``lax.scan`` under
+    ``make_distributed_method_step`` included — is one chunk of length
+    ``T`` of ``dense_colocation(colocation)`` through the chunk replay under
     ``shard_map`` over ``dcfg.data_axis`` (jit-cached like the single-host
     path; the mesh and ``dcfg`` join the cache key). Mule state/colocation
     columns shard, fixed-device state and the freshness sketch replicate.
+    With ``dcfg.rebucket_every > 0`` the replay runs in chunks of that
+    length and re-buckets between them (``run_population_streamed``).
 
     state:   ``to_distributed_state(init_population(...), dcfg)`` layout.
     dcfg:    ``repro.core.distributed.DistributedConfig`` — collective
@@ -1053,57 +891,18 @@ def run_population_distributed(state: Dict[str, Any],
     if key is None:
         raise TypeError("run_population_distributed() missing required "
                         "argument: 'key'")
-    fid, exch, pos, area, act = _colocation_tensors(colocation)
-    n_steps = fid.shape[0]
-    dcfg = _resolve_ring_bits(dcfg, jnp.max(area) if area.size else 0)
+    gen = dense_colocation(colocation)
     rb = int(getattr(dcfg, "rebucket_every", 0) or 0)
-    if rb > 0:
-        # Re-bucketing swaps live state between chunks, so the materialized
-        # run delegates to the streamed engine with one chunk per rebucket
-        # window — streamed == materialized is pinned bitwise, so this is
-        # the same replay with swap points inserted.
-        if eval_fn is not None and eval_every and rb % eval_every:
-            raise ValueError(
-                f"rebucket_every={rb} must be a multiple of "
-                f"eval_every={eval_every} so drift checks land on eval "
-                "boundaries")
-        from repro.mobility.streaming import compact_colocation
-        return run_population_streamed(
-            state, compact_colocation(colocation), batches, train_fn,
-            dcfg.pop, key, n_steps=n_steps, chunk_len=rb,
-            eval_every=eval_every, eval_fn=eval_fn, method=method,
-            context=context, donate=donate, mesh=mesh, dcfg=dcfg)
-    if mesh is None:
-        mesh = _auto_mesh(method, fid.shape[1], dcfg)
-    _check_mule_sharding(fid.shape[1], mesh, dcfg)
-    stacked = None if callable(batches) else batches
-    # commit every input to the mesh explicitly: each device holds its
-    # shard of the mule columns (and, across processes, each process
-    # materializes only its own shards)
-    from jax.sharding import PartitionSpec as P
-    from repro.launch.multiprocess import put_global, put_global_tree
-    in_specs, _ = _distributed_specs(state, batches, dcfg, vmapped=False,
-                                     area_dyn=area.ndim == 2)
-    state = put_global_tree(state, mesh, in_specs[0])
-    fid, exch, pos, area, act = (
-        put_global(x, mesh, s) for x, s in
-        zip((fid, exch, pos, area, act), in_specs[1:6]))
-    if stacked is not None:
-        stacked = put_global_tree(stacked, mesh, in_specs[6])
-    if context is not None:
-        context = put_global_tree(
-            context, mesh, jax.tree.map(lambda _: P(), context))
-    key = put_global(key, mesh, P())
-    fn = get_compiled_replay(state, fid, exch, pos, area, act, batches,
-                             context, key, train_fn, dcfg.pop, method=method,
-                             eval_every=eval_every, eval_fn=eval_fn,
-                             donate=donate, mesh=mesh, dcfg=dcfg)
-    state, last, evals = fn(state, fid, exch, pos, area, act, stacked,
-                            context, key)
-    n_ev = n_steps // eval_every if (eval_fn is not None and eval_every) else 0
-    steps = (np.arange(n_ev) + 1) * eval_every - 1 if n_ev else \
-        np.zeros((0,), int)
-    return state, {"last_fid": last, "eval_steps": steps, "evals": evals}
+    if rb > 0 and eval_fn is not None and eval_every and rb % eval_every:
+        raise ValueError(
+            f"rebucket_every={rb} must be a multiple of "
+            f"eval_every={eval_every} so drift checks land on eval "
+            "boundaries")
+    return run_population_streamed(
+        state, gen, batches, train_fn, dcfg.pop, key,
+        chunk_len=rb if rb > 0 else gen.n_steps, eval_every=eval_every,
+        eval_fn=eval_fn, method=method, context=context, donate=donate,
+        mesh=mesh, dcfg=dcfg)
 
 
 def run_population_distributed_loop(state: Dict[str, Any],
@@ -1144,9 +943,8 @@ def run_population_distributed_loop(state: Dict[str, Any],
                   "area": P(ax), "active": P(ax), "t": P()}
     cache_key = ("dist_loop_step", method, dcfg, mesh, train_fn,
                  _sig(state), area_dyn)
-    step = _JIT_CACHE.get(cache_key)
-    if step is None:
-        _STATS["misses"] += 1
+
+    def build():
         step_core = make_distributed_method_step(method, train_fn, dcfg,
                                                  mesh=mesh)
 
@@ -1154,16 +952,12 @@ def run_population_distributed_loop(state: Dict[str, Any],
             _STATS["traces"] += 1      # python side effect: fires per trace
             return step_core(st, info, bt, k)
 
-        step = jax.jit(jax.shard_map(
+        return jax.jit(jax.shard_map(
             counted, mesh=mesh,
             in_specs=(state_specs, info_specs, P(), P()),
             out_specs=state_specs, check_vma=False))
-        _JIT_CACHE[cache_key] = step
-        while len(_JIT_CACHE) > _JIT_CACHE_MAX:
-            _JIT_CACHE.popitem(last=False)
-    else:
-        _STATS["hits"] += 1
-        _JIT_CACHE.move_to_end(cache_key)
+
+    step = _memo(cache_key, build)
 
     dynamic = callable(batches)
     last_fid = jnp.zeros((n_mules,), jnp.int32)

@@ -3,9 +3,11 @@
 The paper's headline results (Figs 6-9, Table 1) are seed-averaged curves
 across five methods. Running them as S x 5 independent ``run_population``
 calls pays Python dispatch and (without the jit cache) a retrace per cell;
-``run_sweep`` instead vmaps the scan over a stacked seed axis so the whole
-seed batch is ONE XLA program executed once — the same
-amortize-across-clients lever FedAvg-style simulators use.
+``run_sweep`` instead runs the engine's one chunk replay ``vmap``ped over a
+stacked seed axis, as one chunk of the whole horizon over the stacked
+schedule (``dense_colocation``), so the whole seed batch is ONE XLA program
+executed once — the same amortize-across-clients lever FedAvg-style
+simulators use.
 
 Batching rules:
 
@@ -34,17 +36,19 @@ bitwise-equal to a sequential ``run_population_distributed`` call on the
 same mesh (``tests/test_distributed.py`` pins it). All five
 ``METHODS_MOBILE`` sweep distributed: the peer-encounter baselines' ring
 ``ppermute`` exchange batches under the seed vmap like any other
-collective (``tests/test_distributed_engine.py`` pins a gossip lane).
+collective (``tests/test_distributed_engine.py`` pins a gossip lane). The
+lanes share one mule layout, so a sweep does not re-bucket
+(``DistributedConfig.rebucket_every`` is not read here).
 """
 from __future__ import annotations
 
 from typing import Any, Callable, Dict, Optional, Sequence, Tuple, Union
 
 import jax
-import numpy as np
 
 from repro.core.population import PopulationConfig, TrainFn
-from repro.scenarios.engine import _colocation_tensors, get_compiled_replay
+from repro.mobility.streaming import dense_colocation
+from repro.scenarios.engine import _replay
 
 SweepResult = Tuple[Dict[str, Any], Dict[str, Any]]
 
@@ -61,11 +65,7 @@ def stack_colocations(cos: Sequence[Dict[str, Any]]) -> Dict[str, Any]:
     The churn mask stacks too (``active`` [S, T, M]); seeds without one
     stack as all-ones lanes, so dense and churned seeds can share a sweep.
     """
-    per = [_colocation_tensors(co) for co in cos]
-    fid, exch, pos, area, act = (stack_trees([p[i] for p in per])
-                                 for i in range(5))
-    return {"fixed_id": fid, "exchange": exch, "pos": pos, "area": area,
-            "active": act}
+    return stack_trees([dense_colocation(co).arrays() for co in cos])
 
 
 def run_sweep(states: Dict[str, Any], colocations: Dict[str, Any],
@@ -105,31 +105,18 @@ def run_sweep(states: Dict[str, Any], colocations: Dict[str, Any],
     if donate and not isinstance(methods, str):
         raise ValueError("donate=True replays would reuse donated state "
                          "across methods; pass a single method")
-    fid, exch, pos, area, act = _colocation_tensors(colocations)
-    if fid.ndim == 2:                      # shared schedule -> broadcast
+    gen = dense_colocation(colocations)
+    if gen.arrays()["fixed_id"].ndim == 2:       # shared schedule -> broadcast
         s = jax.tree.leaves(keys)[0].shape[0]
-        fid, exch, pos, area, act = (jnp.broadcast_to(l, (s,) + l.shape)
-                                     for l in (fid, exch, pos, area, act))
-    n_steps = int(fid.shape[1])
-    if mesh is not None:
-        from repro.scenarios.engine import _check_mule_sharding
-        _check_mule_sharding(int(fid.shape[2]), mesh, dcfg)
-    stacked = None if callable(batches) else batches
+        gen = dense_colocation(jax.tree.map(
+            lambda l: jnp.broadcast_to(l, (s,) + l.shape), gen.arrays()))
 
     def one(method: str) -> SweepResult:
-        fn = get_compiled_replay(states, fid, exch, pos, area, act, batches,
-                                 context, keys, train_fn, cfg, method=method,
-                                 eval_every=eval_every, eval_fn=eval_fn,
-                                 vmapped=True, donate=donate, mesh=mesh,
-                                 dcfg=dcfg)
-        final, last, evals = fn(states, fid, exch, pos, area, act, stacked,
-                                context, keys)
-        n_ev = (n_steps // eval_every
-                if (eval_fn is not None and eval_every) else 0)
-        steps = (np.arange(n_ev) + 1) * eval_every - 1 if n_ev else \
-            np.zeros((0,), int)
-        return final, {"last_fid": last, "eval_steps": steps,
-                       "evals": evals}
+        return _replay(states, gen, batches, train_fn, cfg, keys,
+                       n_steps=gen.n_steps, chunk_len=gen.n_steps,
+                       eval_every=eval_every, eval_fn=eval_fn, method=method,
+                       context=context, donate=donate, mesh=mesh,
+                       dcfg=dcfg if mesh is not None else None, vmapped=True)
 
     if isinstance(methods, str):
         return one(methods)

@@ -7,7 +7,10 @@ for every registered scenario (chunk boundaries included),
 ``run_population_streamed`` against ``run_population`` for every method,
 evals included, single-host against distributed, and the procedural
 commuter stream against an independent host re-derivation of its dwell
-cadence. The cache tests pin the perf claim: the compiled chunk program
+cadence. ``run_population`` is the same compiled chunk program over
+``dense_colocation`` (the materialized tensors, sliced), so each streamed
+pin compares a generator's ``expand`` with those tensors through one
+program. The cache tests pin the perf claim: the compiled chunk program
 must not depend on the horizon.
 """
 import jax
@@ -22,8 +25,8 @@ except ImportError:  # tier-1 container: fixed-seed fallback sweep
 
 from repro.core.distributed import DistributedConfig, to_distributed_state
 from repro.mobility import (CommuterStream, commuter_stream,
-                            compact_colocation, dwell_exchange_flags,
-                            materialize_generator)
+                            compact_colocation, dense_colocation,
+                            dwell_exchange_flags, materialize_generator)
 from repro.scenarios import (get_scenario, list_scenarios, run_population,
                              run_population_streamed, scenario_generator)
 from repro.scenarios.engine import (_colocation_tensors, jit_cache_clear,
@@ -73,11 +76,12 @@ def test_every_scenario_streams_bitwise(seed, n_mules, n_steps, chunk_len):
         gen = scenario_generator(spec, seed, n_mules, n_steps,
                                  colocation=co)
         fid, exch, pos, area, act = _colocation_tensors(co)
-        got = _expand_chunked(gen, n_steps, chunk_len)
-        for key, ref in (("fixed_id", fid), ("exchange", exch),
-                         ("pos", pos), ("active", act), ("area", area)):
-            assert np.array_equal(got[key], np.asarray(ref)), \
-                f"{name}: streamed {key} != host tensors"
+        for g in (gen, dense_colocation(co)):
+            got = _expand_chunked(g, n_steps, chunk_len)
+            for key, ref in (("fixed_id", fid), ("exchange", exch),
+                             ("pos", pos), ("active", act), ("area", area)):
+                assert np.array_equal(got[key], np.asarray(ref)), \
+                    f"{name}: {type(g).__name__} {key} != host tensors"
 
 
 def test_scenario_generator_reuses_prebuilt_colocation():
@@ -200,6 +204,66 @@ def test_streamed_evals_match_materialized():
     assert_trees_bitwise(ref, st)
     assert_trees_bitwise(aux_ref["evals"], aux["evals"])
     np.testing.assert_array_equal(aux_ref["eval_steps"], aux["eval_steps"])
+
+
+def test_materialized_replay_is_one_dense_chunk():
+    """run_population is the chunk replay over dense_colocation in one
+    chunk of length T: the streamed call on the same arguments hits the
+    program run_population compiled, with no retrace, and agrees bitwise."""
+    pop, co, batch_fn, train_fn, pcfg = linear_population_setup(
+        n_mules=M, n_steps=T)
+    key = jax.random.PRNGKey(13)
+    jit_cache_clear()
+    ref, aux_ref = run_population(pop, co, batch_fn, train_fn, pcfg, key,
+                                  method="gossip")
+    assert jit_cache_stats() == {"traces": 1, "hits": 0, "misses": 1}
+    st, aux = run_population_streamed(pop, dense_colocation(co), batch_fn,
+                                      train_fn, pcfg, key, chunk_len=T,
+                                      method="gossip", donate=False)
+    assert jit_cache_stats() == {"traces": 1, "hits": 1, "misses": 1}
+    assert_trees_bitwise(ref, st, "dense chunk diverged from run_population")
+    assert_trees_bitwise(aux_ref["last_fid"], aux["last_fid"])
+
+
+def test_one_chunk_takes_a_partial_eval_tail():
+    """One chunk whose length is not a multiple of eval_every evals like
+    run_population (a partial tail block after the last eval) and like a
+    multi-chunk run whose chunks are whole eval blocks."""
+    pop, co, batch_fn, train_fn, pcfg = linear_population_setup(
+        n_mules=M, n_steps=T)
+    key = jax.random.PRNGKey(17)
+
+    def eval_fn(state, last):
+        return {"wmean": jax.tree.map(lambda l: l.mean(),
+                                      state["mule_models"]),
+                "lmax": last.max()}
+
+    ref, aux_ref = run_population(pop, co, batch_fn, train_fn, pcfg, key,
+                                  eval_every=7, eval_fn=eval_fn)
+    np.testing.assert_array_equal(aux_ref["eval_steps"], [6, 13, 20, 27])
+    for gen, chunk_len in ((dense_colocation(co), T),
+                           (compact_colocation(co), 14)):
+        st, aux = run_population_streamed(pop, gen, batch_fn, train_fn,
+                                          pcfg, key, n_steps=T,
+                                          chunk_len=chunk_len, eval_every=7,
+                                          eval_fn=eval_fn, donate=False)
+        assert_trees_bitwise(ref, st)
+        assert_trees_bitwise(aux_ref["evals"], aux["evals"])
+        np.testing.assert_array_equal(aux_ref["eval_steps"],
+                                      aux["eval_steps"])
+
+
+@pytest.mark.parametrize("make_gen", [dense_colocation, compact_colocation])
+def test_multi_chunk_run_rejects_misaligned_evals(make_gen):
+    """Two chunks (T - 1 steps, then one) with eval_every not dividing the
+    chunk length still raise, whatever the generator."""
+    pop, co, batch_fn, train_fn, pcfg = linear_population_setup(
+        n_mules=M, n_steps=T)
+    with pytest.raises(ValueError, match="multiple of"):
+        run_population_streamed(pop, make_gen(co), batch_fn, train_fn, pcfg,
+                                jax.random.PRNGKey(0), n_steps=T,
+                                chunk_len=T - 1, eval_every=5,
+                                eval_fn=lambda s, l: l.max(), donate=False)
 
 
 def test_streamed_rejects_misaligned_eval_chunks():

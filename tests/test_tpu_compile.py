@@ -98,17 +98,21 @@ def test_mlmule_replay_fits_one_chip(one_chip):
     """The one-chip replay ``chip_smoke.py`` runs: M=512 mule CNNs at their
     published widths, compiled with the state donated."""
     import chip_smoke
-    from repro.scenarios.engine import _colocation_tensors, get_compiled_replay
+    from repro.scenarios.engine import (dense_colocation,
+                                        get_compiled_chunk_replay)
 
     w = chip_smoke.make_workload(0, 512, 24)
     state = jax.eval_shape(lambda: chip_smoke.initial_state(w))
-    cols = _colocation_tensors(w.colocation)
-    fn = get_compiled_replay(state, *cols, w.batch_fn, w.context, w.key,
-                             w.train_fn, w.pcfg, method="mlmule",
-                             eval_every=None, eval_fn=None, donate=True)
+    gen = dense_colocation(w.colocation)
+    fn = get_compiled_chunk_replay(
+        state, gen, gen.arrays(), w.batch_fn, w.context, w.key, w.train_fn,
+        w.pcfg, method="mlmule", eval_every=None, eval_fn=None,
+        chunk_len=gen.n_steps, donate=True)
     place = lambda x: _spec(one_chip, x.shape, x.dtype)
     compiled = fn.lower(jax.tree.map(place, state),
-                        *map(place, cols), None,
+                        _spec(one_chip, (gen.n_mules,), jnp.int32),
+                        _spec(one_chip, (), jnp.int32),
+                        jax.tree.map(place, gen.arrays()), None,
                         jax.tree.map(place, w.context),
                         place(w.key)).compile()
     mem = compiled.memory_analysis()
