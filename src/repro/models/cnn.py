@@ -57,9 +57,96 @@ def _pool(x):
                                  (1, 2, 2, 1), "VALID")
 
 
+# Block 1 runs pool-window-major: its activations are [B, H/2, W/2, 4*C],
+# the four pixels (py, px) of each 2x2 pool window, in row-major order, as
+# four groups of C channels on the minor axis. At C = 32 that axis is 128
+# lanes wide; in NHWC the 32 channels would fill a quarter of the 128 lanes,
+# and every pass over the full-resolution tensors would move four times
+# their bytes.
+
+
+def _space_to_depth(x):
+    """[B, H, W, C] -> [B, H/2, W/2, 4C], channels ordered (py, px, c)."""
+    b, h, w, c = x.shape
+    if h % 2 or w % 2:
+        raise ValueError(f"the image mule's 2x2 pool needs even image sides, "
+                         f"got {h}x{w}")
+    x = x.reshape(b, h // 2, 2, w // 2, 2, c).transpose(0, 1, 3, 2, 4, 5)
+    return x.reshape(b, h // 2, w // 2, 4 * c)
+
+
+# Row ``ky + 2`` of the 3x3 kernel padded by two on each side that tap
+# ``d`` of the space-to-depth conv applies from input pixel row ``p`` to
+# output pixel row ``a``: full-resolution row ``2i + a + ky - 1`` is block
+# row ``i + d - 1``, pixel row ``p``, so ``ky = 2(d - 1) + p - a + 1``.
+_TAPS = tuple(2 * d + p - a + 1 for d in range(3) for p in range(2)
+              for a in range(2))
+
+
+def _expand_conv1(w):
+    """A [3, 3, C, F] kernel as the [3, 3, 4C, 4F] kernel that applies it
+    between space-to-depth layouts: ``W'[d, e, (p, q, c), (a, b, f)] =
+    W[2d + p - a - 1, 2e + q - b - 1, c, f]`` where both indices lie in
+    0..2, else 0. Exact data movement (pad, slice, stack): no weight is
+    rounded."""
+    c, f = w.shape[2:]
+    wp = jnp.pad(w, ((2, 2), (2, 2), (0, 0), (0, 0)))
+    rows = jnp.stack([wp[i] for i in _TAPS])               # [(d,p,a), 7, C, F]
+    taps = jnp.stack([rows[:, i] for i in _TAPS], axis=1)  # [(d,p,a), (e,q,b)]
+    taps = taps.reshape(3, 2, 2, 3, 2, 2, c, f).transpose(0, 3, 1, 4, 6, 2, 5, 7)
+    return taps.reshape(3, 3, 4 * c, 4 * f)
+
+
+def _bn_groups(x, p, eps=1e-5):
+    """``_bn`` over a [..., 4C] pool-window-major activation: statistics per
+    channel over the batch, the positions and the four groups. The
+    statistics fold the groups after the lane-wide reductions, so no
+    [..., 4, C] view of the activation is formed."""
+    per_group = lambda v: jnp.tile(v.reshape(4, -1).mean(0), 4)
+    d = x - per_group(jnp.mean(x, axis=(0, 1, 2)))
+    var = per_group(jnp.mean(d * d, axis=(0, 1, 2)))
+    return (d * jax.lax.rsqrt(var + eps) * jnp.tile(p["scale"], 4)
+            + jnp.tile(p["bias"], 4))
+
+
+def _group_max(x):
+    """The 2x2 max pool of a [..., 4C] pool-window-major activation: the
+    maximum over its four groups, [..., C]."""
+    g = jnp.split(x, 4, axis=-1)
+    return jnp.maximum(jnp.maximum(g[0], g[1]), jnp.maximum(g[2], g[3]))
+
+
+_pool_groups = jax.custom_vjp(_group_max)
+
+
+def _pool_groups_fwd(x):
+    return _group_max(x), x
+
+
+def _pool_groups_bwd(x, dy):
+    """Each window's gradient to its first maximum in row-major order, as
+    ``select-and-scatter`` routes the gradient of ``_pool``; JAX's own
+    gradient of a max would split ties. The first maximum's group is one
+    index per channel, and the gradient one select over all 4C lanes:
+    routed group by group, each group's [..., C] piece and mask would be
+    kept in memory, a quarter-filled lane row each."""
+    g = jnp.split(x, 4, axis=-1)
+    y = _group_max(x)
+    first = jnp.where(g[0] == y, 0, jnp.where(g[1] == y, 1,
+                                              jnp.where(g[2] == y, 2, 3)))
+    group = jax.lax.broadcasted_iota(jnp.int32, x.shape, x.ndim - 1) \
+        // dy.shape[-1]
+    tile = lambda a: jnp.concatenate([a] * 4, axis=-1)
+    return (jnp.where(tile(first) == group, tile(dy), 0.0),)
+
+
+_pool_groups.defvjp(_pool_groups_fwd, _pool_groups_bwd)
+
+
 def cnn_forward(params, images):
-    """images: [B, H, W, C] -> logits [B, n_classes]."""
-    x = _pool(jax.nn.relu(_bn(_conv2d(images, params["conv1"]), params["bn1"])))
+    """images: [B, H, W, C], H and W even -> logits [B, n_classes]."""
+    x = _conv2d(_space_to_depth(images), _expand_conv1(params["conv1"]))
+    x = _pool_groups(jax.nn.relu(_bn_groups(x, params["bn1"])))
     x = _pool(jax.nn.relu(_bn(_conv2d(x, params["conv2"]), params["bn2"])))
     x = x.reshape(x.shape[0], -1)
     x = jax.nn.relu(x @ params["fc1"] + params["fc1_b"])

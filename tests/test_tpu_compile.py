@@ -7,13 +7,16 @@ published mule-CNN width (D = 546,484 parameters per mule) with the tiles
 their ops would choose, and the one-chip ``mlmule`` replay at M=512, so
 every later change is held to the chip's compiler at no chip time.
 
-Nothing runs; a compile that passes is not a chip run. The topology is
+Nothing runs; a compile that passes is not a chip run. The image mule's
+vmapped SGD step is held to its layout: block 1 pool-window-major, with no
+32-channel tensor at full resolution. The topology is
 described inside a module-scoped fixture (only the worker given this file
 loads the TPU compiler), and JAX's persistent compilation cache is off
 around the compiles: what they would write cannot be read back without a
 chip.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -120,3 +123,29 @@ def test_mlmule_replay_fits_one_chip(one_chip):
             + mem.output_size_in_bytes - mem.alias_size_in_bytes)
     assert used < V5E_HBM_BYTES, f"{used / 2 ** 30:.2f} GiB"
     assert mem.alias_size_in_bytes > 0          # the donated state aliases
+
+
+def test_cnn_block1_is_lane_dense(one_chip):
+    """The vmapped SGD step of M=512 paper CNNs at batch 16: block 1 runs
+    pool-window-major, so no float tensor of a [.., 32, 32, .., 32]
+    full-resolution, 32-channel shape remains, whose channels filled a
+    quarter of the 128 lanes, and only block 2's pool leaves a
+    ``select-and-scatter``."""
+    from repro.configs.mule_cnn import CNNConfig
+    from repro.models.cnn import cnn_forward, init_cnn, xent_loss
+
+    cfg, m, b = CNNConfig(), 512, 16
+    params = jax.eval_shape(jax.vmap(lambda k: init_cnn(k, cfg)),
+                            jax.ShapeDtypeStruct((m, 2), jnp.uint32))
+
+    def sgd(p, x, y):
+        g = jax.grad(lambda p: xent_loss(cnn_forward(p, x), y))(p)
+        return jax.tree.map(lambda w, d: w - 0.05 * d, p, g)
+
+    text = jax.jit(jax.vmap(sgd)).lower(
+        jax.tree.map(lambda l: _spec(one_chip, l.shape), params),
+        _spec(one_chip, (m, b, cfg.image_size, cfg.image_size, cfg.channels)),
+        _spec(one_chip, (m, b), jnp.int32)).compile().as_text()
+    full = re.findall(r"(?:f32|bf16)\[(?:\d+,)*32,32,(?:\d+,)*32\]", text)
+    assert not full, sorted(set(full))
+    assert len(re.findall(r"\sselect-and-scatter\(", text)) == 1
